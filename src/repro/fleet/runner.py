@@ -73,6 +73,7 @@ from repro.fleet.state import (
     workload_spec,
 )
 from repro.fleet.step import FleetObs, FleetStepOut, fleet_step
+from repro.obs.trace import stage, staged
 from repro.scene_jax.observe import (
     TeacherArrays,
     detections_obs,
@@ -194,6 +195,7 @@ class SceneProvider:
             params=shard_fleet(self.params, mesh), mbps=mbps, rtt=rtt)
 
 
+@staged("shortlist")
 def shortlist_windows(cfg: FleetConfig, state: FleetState,
                       neighbor8: jnp.ndarray, k: int) -> jnp.ndarray:
     """Search-coupled candidate shortlist: the [F, K] flattened window
@@ -332,7 +334,8 @@ class DetectorProvider:
                               pair_idx=wl.pair_idx,
                               n_zoom=len(cfg.zoom_levels),
                               cam_salt=state.rng[:, 0])
-        noise_img = render_noise(state.rng, frame, res) * self.noise
+        with stage("crop_to_tokens"):
+            noise_img = render_noise(state.rng, frame, res) * self.noise
 
         if learn_on:
             dets, lc = self._score_learn(cfg, state, sc, dp, lc, kinds,
@@ -382,10 +385,11 @@ class DetectorProvider:
             # un-shortlisted windows read as score-0 detections (empty
             # under any positive threshold), so detections_obs and the
             # step consume the same full [F, C] axis either way
-            arange_f = jnp.arange(f)[:, None]
-            dets = jax.tree.map(
-                lambda x: jnp.zeros((f, c) + x.shape[2:], x.dtype)
-                .at[arange_f, widx].set(x), dets)
+            with stage("heads"):
+                arange_f = jnp.arange(f)[:, None]
+                dets = jax.tree.map(
+                    lambda x: jnp.zeros((f, c) + x.shape[2:], x.dtype)
+                    .at[arange_f, widx].set(x), dets)
         return dets
 
     def _score_chunked(self, sc, dp, kinds, noise_img, p, res):
@@ -463,14 +467,16 @@ class DetectorProvider:
                     par, self.det_cfg, x))(lc.params, tokens)
             payload = tokens
         if k < c:
-            arange_f = jnp.arange(f)[:, None]
-            dets = jax.tree.map(
-                lambda x: jnp.zeros((f, c) + x.shape[2:], x.dtype)
-                .at[arange_f, widx].set(x), dets)
+            with stage("heads"):
+                arange_f = jnp.arange(f)[:, None]
+                dets = jax.tree.map(
+                    lambda x: jnp.zeros((f, c) + x.shape[2:], x.dtype)
+                    .at[arange_f, widx].set(x), dets)
         lc = lc._replace(staged=payload.astype(lc.staged.dtype),
                          staged_widx=widx.astype(jnp.int32))
         return dets, lc
 
+    @staged("distill")
     def learn(self, cfg: FleetConfig, wl: WorkloadSpec, carry,
               state: FleetState, out):
         """Post-step learning hook (called by _episode when `learns`):

@@ -35,6 +35,7 @@ from repro.fleet.state import (
     FleetStatics,
     WorkloadSpec,
 )
+from repro.obs.trace import staged
 
 INF = jnp.inf
 
@@ -327,6 +328,7 @@ def gather_at_zoom(x: jnp.ndarray, zoom_idx: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("cfg", "wl"))
+@staged("controller")
 def fleet_step(cfg: FleetConfig, wl: WorkloadSpec, statics: FleetStatics,
                state: FleetState, obs: FleetObs
                ) -> tuple[FleetState, FleetStepOut]:

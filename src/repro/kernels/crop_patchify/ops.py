@@ -22,7 +22,7 @@ import numpy as np
 from repro.kernels import interpret_mode
 from repro.kernels.crop_patchify.crop_patchify import crop_patchify_batch
 from repro.kernels.crop_patchify.ref import crop_patchify_ref, pixel_bounds
-from repro.obs import span
+from repro.obs.trace import stage
 
 
 def crop_patchify(pos, size, kind, oid, windows, patch_params, *,
@@ -47,9 +47,7 @@ def crop_patchify(pos, size, kind, oid, windows, patch_params, *,
     k = windows.shape[-2]
     if block_k is not None and (block_k <= 0 or k % block_k != 0):
         raise ValueError(f"block_k={block_k} must divide the {k} windows")
-    # host span: times trace/dispatch at this entry point (execution is
-    # async); a no-op unless a repro.obs tracer is active
-    with span("ops/crop_patchify", k=k, use_kernel=use_kernel):
+    with stage("crop_to_tokens"):
         return _crop_patchify(pos, size, kind, oid, windows, patch_params,
                               noise, patch=patch, res=res,
                               min_visible=min_visible, dtype=dtype,

@@ -20,7 +20,6 @@ import numpy as np
 from repro.kernels import interpret_mode
 from repro.kernels.neighbor_score.neighbor_score import neighbor_score_batch
 from repro.kernels.neighbor_score.ref import neighbor_scores_ref
-from repro.obs import span
 
 LANES = 128
 
@@ -61,14 +60,10 @@ def neighbor_scores(shape_mask: jnp.ndarray, has_boxes: jnp.ndarray,
     Scores match core/neighbor.score_candidates on candidate cells;
     non-candidates are scored too (same formula) and masked by `cand`.
     """
-    # host span: times trace/dispatch at this entry point (execution is
-    # async); a no-op unless a repro.obs tracer is active
-    with span("ops/neighbor_scores", b=int(shape_mask.shape[0]),
-              use_kernel=use_kernel):
-        return _neighbor_scores(shape_mask, has_boxes, centroids, head,
-                                d_center, overlap, cell_x, cell_y,
-                                neighbor8, use_kernel=use_kernel,
-                                interpret=interpret_mode(), block_b=block_b)
+    return _neighbor_scores(shape_mask, has_boxes, centroids, head,
+                            d_center, overlap, cell_x, cell_y, neighbor8,
+                            use_kernel=use_kernel,
+                            interpret=interpret_mode(), block_b=block_b)
 
 
 @partial(jax.jit, static_argnames=("use_kernel", "interpret", "block_b"))

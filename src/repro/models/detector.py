@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from repro.configs.base import DetectorConfig, VisionConfig
 from repro.models import vit
 from repro.models.layers import Params, conv2d, conv_init
+from repro.obs.trace import stage, staged
 
 
 class Detections(NamedTuple):
@@ -66,6 +67,7 @@ def detector_init(key, cfg: DetectorConfig) -> Params:
     }
 
 
+@staged("heads")
 def neck_features(bb: Params, feats: jnp.ndarray) -> jnp.ndarray:
     """backbone feature map [B, g, g, D] -> post-neck map [B, g, g, F].
 
@@ -77,6 +79,7 @@ def neck_features(bb: Params, feats: jnp.ndarray) -> jnp.ndarray:
     return jax.nn.gelu(conv2d(bb["neck"]["smooth"], f))     # [B, g, g, F]
 
 
+@staged("heads")
 def head_outputs(heads: Params, f: jnp.ndarray):
     """post-neck features [B, g, g, F] -> raw head outputs — the
     per-query fine-tuned slice of the forward (paper: the final 3
@@ -103,7 +106,8 @@ def detector_raw(params: Params, cfg: DetectorConfig, images: jnp.ndarray, *,
     bb = params["backbone"]
     if freeze_backbone:
         bb = jax.lax.stop_gradient(bb)
-    feats = vit.vit_features(bb["vit"], bcfg, images)      # [B, g, g, D]
+    with stage("backbone"):
+        feats = vit.vit_features(bb["vit"], bcfg, images)  # [B, g, g, D]
     return _neck_and_heads(params, bb, feats)
 
 
@@ -117,7 +121,8 @@ def detector_raw_tokens(params: Params, cfg: DetectorConfig,
     bb = params["backbone"]
     if freeze_backbone:
         bb = jax.lax.stop_gradient(bb)
-    feats = vit.vit_features_tokens(bb["vit"], bcfg, tokens)
+    with stage("backbone"):
+        feats = vit.vit_features_tokens(bb["vit"], bcfg, tokens)
     return _neck_and_heads(params, bb, feats)
 
 
@@ -132,7 +137,8 @@ def detector_neck_feats_tokens(params: Params, cfg: DetectorConfig,
     distillation re-runs zero backbone compute."""
     bcfg = _backbone_cfg(cfg)
     bb = params["backbone"]
-    feats = vit.vit_features_tokens(bb["vit"], bcfg, tokens)
+    with stage("backbone"):
+        feats = vit.vit_features_tokens(bb["vit"], bcfg, tokens)
     return neck_features(bb, feats)
 
 
@@ -171,6 +177,7 @@ def detector_forward_tokens(params: Params, cfg: DetectorConfig,
                               *detector_raw_tokens(params, cfg, tokens))
 
 
+@staged("heads")
 def _decode_detections(cfg: DetectorConfig, cls_logits, box_raw,
                        obj_logits) -> Detections:
     B, g = cls_logits.shape[0], cls_logits.shape[1]

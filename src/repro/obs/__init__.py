@@ -6,9 +6,10 @@ Three layers, one per kind of blindness the fleet pipeline had:
               jit'd episode scan (shortlist hit-rate, chosen-vs-oracle
               rank, EWMA labels, budget counters) — per-step [E, F]
               device outputs, zero cost when off
-  trace.py    host span API -> Chrome trace JSON (build / compile /
-              steady-state / bench-leg phases; chrome://tracing,
-              Perfetto) with optional jax.profiler annotation
+  trace.py    the step's stage names (`STAGES`, `stage` named scopes,
+              read from a device trace) and host spans (build / compile
+              / steady-state / bench-leg phases) that always reach the
+              profiler's trace, optionally recorded as Chrome trace JSON
   events.py   FleetResult -> chunked JSONL event stream with per-camera
               health summaries (`serve --fleet N --telemetry PATH|-`)
 
@@ -24,11 +25,14 @@ from repro.obs.metrics import (
     summarize_metrics,
 )
 from repro.obs.trace import (
+    STAGES,
     Tracer,
     activate,
     active_tracer,
     deactivate,
     span,
+    stage,
+    staged,
     tracing,
 )
 from repro.obs.events import (
@@ -45,11 +49,14 @@ __all__ = [
     "median_valid_rank",
     "step_metrics",
     "summarize_metrics",
+    "STAGES",
     "Tracer",
     "activate",
     "active_tracer",
     "deactivate",
     "span",
+    "stage",
+    "staged",
     "tracing",
     "SCHEMA_VERSION",
     "episode_events",

@@ -47,6 +47,8 @@ from dataclasses import dataclass
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import staged
+
 # flag on MetricsSpec -> the FleetMetrics keys it owns
 METRIC_KEYS = {
     "ewma": ("ewma_label_mean",),
@@ -77,6 +79,7 @@ class MetricsSpec:
                      if getattr(self, flag) for k in keys)
 
 
+@staged("telemetry")
 def step_metrics(spec: MetricsSpec, cfg, provider, state_pre, state_post,
                  obs, out) -> dict:
     """One step's FleetMetrics — a {name: [F] array} pytree.

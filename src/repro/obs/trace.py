@@ -1,80 +1,107 @@
-"""Host-side structured traces: lightweight spans -> Chrome trace JSON.
+"""Host spans and device stage names, both readable from one profile.
 
-The fleet pipeline's wall time hides in phases the step outputs can't
-see — provider build, jit trace + XLA compile, steady-state scan, each
-bench leg. `Tracer` records named spans (a `with span("fleet/compile")`
-context) with microsecond timestamps and exports the Chrome trace event
-format, so a whole benchmark run opens directly in `chrome://tracing` /
-Perfetto and "is the 9.5x coming from patchify or the forward" becomes a
-zoom, not a printf hunt.
+Two kinds of name, one per side of the dispatch:
 
-Design constraints:
+  * stages — `STAGES` names the stages of the fleet step, and
+    `stage(name)` (or the `staged(name)` decorator) opens a
+    `jax.named_scope` for one. A scope changes only the `op_name`
+    metadata of the ops traced inside it, never the compiled ops, so
+    it costs nothing at run time; a profiler trace of the chip, read
+    with that metadata, gives each stage's device time.
+  * host spans — `span(name)` always opens a
+    `jax.profiler.TraceAnnotation`, so any `jax.profiler.trace` of the
+    program shows its host phases beside the device ops. With a
+    `Tracer` active it also records the span as Chrome trace JSON
+    (`chrome://tracing`, Perfetto). A span times host work: call it
+    outside jitted code, around work that ends in `block_until_ready`.
 
-  * zero overhead when no tracer is active: the module-level `span()`
-    returns a shared nullcontext, so instrumented library code
-    (prepare_fleet_run, the kernels' ops entry points, the engine shims)
-    costs nothing in normal runs;
-  * spans on ops entry points measure *host* time (trace/dispatch) —
-    inside jit that is trace+lowering cost, which is exactly the
-    compile-phase attribution the ROADMAP's perf items need;
-  * optional `jax_profiler=True` additionally opens a
-    `jax.profiler.TraceAnnotation` per span so spans line up with
-    device timelines captured by `jax.profiler.trace`.
+The Chrome JSON is stamped on the wall clock in microseconds
+(`time.time_ns()`), the clock the profiler's host events carry:
+`jax.profiler.ProfileData` gives them relative to the
+`profile_start_time` stat of the trace's "Task Environment" plane,
+itself wall-clock nanoseconds. So `ts * 1e3 - profile_start_time` puts
+a Chrome span on the xplane's timeline.
 
 Usage:
 
-    from repro.obs.trace import span, tracing
+    from repro.obs.trace import span, stage, tracing
 
     with tracing("run_trace.json"):          # activate + save on exit
         with span("build", provider="scene"):
             ...
+
+    with stage("controller"):                # inside traced code
+        ...
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
 import time
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 
-_NULL = nullcontext()
+import jax
+
+# The stages of one fleet step, in step order. An op's stage is the
+# first component of its `op_name` path that is listed here.
+STAGES = ("scene_advance", "oracle_grade", "shortlist", "crop_to_tokens",
+          "backbone", "heads", "controller", "distill", "telemetry")
+
+
+def stage(name: str):
+    """`jax.named_scope(name)` for one of `STAGES`; any other name is
+    refused, so every scope is one the trace reduction can read."""
+    if name not in STAGES:
+        raise ValueError(f"unknown stage {name!r}; STAGES: "
+                         f"{', '.join(STAGES)}")
+    return jax.named_scope(name)
+
+
+def staged(name: str):
+    """Decorator form of `stage`: the function's ops carry stage `name`.
+    A fresh scope is opened per call, so concurrent or nested traces do
+    not share one. Place it under `jax.jit` so the scope is inside the
+    traced body."""
+    stage(name)
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with stage(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
 
 
 class Tracer:
     """Span recorder exporting the Chrome trace event format."""
 
-    def __init__(self, *, jax_profiler: bool = False):
+    def __init__(self):
         self.events: list[dict] = []
-        self.jax_profiler = jax_profiler
-        self._t0 = time.perf_counter()
         self._lock = threading.Lock()
 
     @contextmanager
     def span(self, name: str, **args):
-        """Record one complete ("ph": "X") span around the with-body.
-        Extra kwargs land in the event's `args` (must be JSON-native)."""
-        ann = None
-        if self.jax_profiler:
-            import jax
-
-            ann = jax.profiler.TraceAnnotation(name)
-            ann.__enter__()
-        start = time.perf_counter()
-        try:
-            yield self
-        finally:
-            dur = time.perf_counter() - start
-            if ann is not None:
-                ann.__exit__(None, None, None)
-            ev = {"name": name, "ph": "X", "pid": os.getpid(),
-                  "tid": threading.get_ident(),
-                  "ts": (start - self._t0) * 1e6, "dur": dur * 1e6}
-            if args:
-                ev["args"] = {k: v if isinstance(
-                    v, (int, float, str, bool, type(None))) else str(v)
-                    for k, v in args.items()}
-            with self._lock:
-                self.events.append(ev)
+        """Record one complete ("ph": "X") span around the with-body,
+        inside a profiler annotation of the same name. Extra kwargs land
+        in the event's `args` (must be JSON-native)."""
+        with jax.profiler.TraceAnnotation(name):
+            start = time.time_ns()
+            try:
+                yield self
+            finally:
+                dur = time.time_ns() - start
+                ev = {"name": name, "ph": "X", "pid": os.getpid(),
+                      "tid": threading.get_ident(),
+                      "ts": start * 1e-3, "dur": dur * 1e-3}
+                if args:
+                    ev["args"] = {k: v if isinstance(
+                        v, (int, float, str, bool, type(None))) else str(v)
+                        for k, v in args.items()}
+                with self._lock:
+                    self.events.append(ev)
 
     def to_chrome(self) -> dict:
         """The chrome://tracing / Perfetto JSON object."""
@@ -95,10 +122,10 @@ class Tracer:
 _active: Tracer | None = None
 
 
-def activate(tracer: Tracer | None = None, **kwargs) -> Tracer:
-    """Install `tracer` (or a fresh Tracer(**kwargs)) as the active one."""
+def activate(tracer: Tracer | None = None) -> Tracer:
+    """Install `tracer` (or a fresh Tracer) as the active one."""
     global _active
-    _active = tracer if tracer is not None else Tracer(**kwargs)
+    _active = tracer if tracer is not None else Tracer()
     return _active
 
 
@@ -114,20 +141,20 @@ def active_tracer() -> Tracer | None:
 
 
 def span(name: str, **args):
-    """Span on the active tracer — a shared no-op context when none is
-    active, so instrumentation in hot entry points is free by default."""
+    """Span on the active tracer; with none active, the profiler
+    annotation alone (a no-op unless a profiler trace is running)."""
     t = _active
     if t is None:
-        return _NULL
+        return jax.profiler.TraceAnnotation(name)
     return t.span(name, **args)
 
 
 @contextmanager
-def tracing(path: str | None = None, *, jax_profiler: bool = False):
+def tracing(path: str | None = None):
     """Activate a fresh tracer for the with-body; save Chrome trace JSON
     to `path` on exit (when given) and restore the previous tracer."""
     prev = _active
-    t = activate(Tracer(jax_profiler=jax_profiler))
+    t = activate(Tracer())
     try:
         yield t
     finally:

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.cell_rasterize.ops import cell_rasterize, window_arrays
+from repro.obs.trace import staged
 from repro.scene_jax.scene import SceneFleetParams, SceneSpec, SceneState, \
     kind_mask
 from repro.serving.teachers import TEACHERS
@@ -113,6 +114,7 @@ def grid_windows(grid, zoom_levels=(1.0, 2.0, 3.0)) -> jnp.ndarray:
     return jnp.asarray(window_arrays(grid, zoom_levels))
 
 
+@staged("heads")
 def detections_obs(dets, windows: jnp.ndarray, pair_cls: jnp.ndarray,
                    thresh: jnp.ndarray, geo_thresh: jnp.ndarray,
                    acc_true: jnp.ndarray, *, n_zoom: int = 3) -> SceneObs:
@@ -177,6 +179,7 @@ def detections_obs(dets, windows: jnp.ndarray, pair_cls: jnp.ndarray,
 
 
 @partial(jax.jit, static_argnames=("spec", "task_id", "pair_idx", "n_zoom"))
+@staged("oracle_grade")
 def observe_all_cells(spec: SceneSpec, teach: TeacherArrays,
                       params: SceneFleetParams, state: SceneState,
                       t: jnp.ndarray, windows: jnp.ndarray, *,

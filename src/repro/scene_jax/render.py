@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.data.scene import PERSON
+from repro.obs.trace import staged
 
 _RENDER_SALT = 0x9E4DE
 # (oid * 2654435761) % 97 without the 64-bit product: reduce both factors
@@ -127,6 +128,7 @@ def render_crop(pos, size, kind, oid, window, *, res: int = 64,
 
 @partial(jax.jit,
          static_argnames=("res", "min_visible"))
+@staged("crop_to_tokens")
 def render_fleet_crops(pos, size, kind, oid, windows, *, res: int = 64,
                        min_visible: float = 0.25,
                        noise: jnp.ndarray | None = None) -> jnp.ndarray:

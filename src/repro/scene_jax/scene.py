@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data.scene import CAR, PERSON, SceneConfig
+from repro.obs.trace import staged
 
 _POI_SALT = 0x5CE7E
 
@@ -289,6 +290,7 @@ def scene_step(spec: SceneSpec, params: SceneFleetParams, keys: jnp.ndarray,
     return jax.vmap(partial(_step_one, spec))(params, keys, state)
 
 
+@staged("scene_advance")
 def advance_scene(spec: SceneSpec, params: SceneFleetParams,
                   rng: jnp.ndarray, state: SceneState, step_idx,
                   stride: int) -> SceneState:

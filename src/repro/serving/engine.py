@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from repro.configs.base import DetectorConfig
 from repro.core import ewma
 from repro.models import detector as det
-from repro.obs import span
 
 
 # Module-level jits, NOT per-engine lambdas: a fresh `jax.jit(lambda ...)`
@@ -141,8 +140,7 @@ def run_fleet_controller(video, workload, tables, budget, trace, *,
         grid=video.grid, workload=workload, budget=budget,
         video=video, tables=tables, trace=trace, acc_table=acc_table,
         approx_miss=approx_miss)
-    with span("engine/fleet_controller", provider="tables"):
-        return prepare_fleet_run(spec, mesh=mesh).episode()
+    return prepare_fleet_run(spec, mesh=mesh).episode()
 
 
 def run_fleet_scene_controller(grid, workload, budget, *, n_cameras: int,
@@ -163,8 +161,7 @@ def run_fleet_scene_controller(grid, workload, budget, *, n_cameras: int,
     spec = FleetRunSpec.from_objects(
         "scene", n_cameras=n_cameras, n_steps=n_steps, seed=seed,
         grid=grid, workload=workload, budget=budget, **scene_kwargs)
-    with span("engine/fleet_controller", provider="scene"):
-        return prepare_fleet_run(spec, mesh=mesh).episode()
+    return prepare_fleet_run(spec, mesh=mesh).episode()
 
 
 def run_fleet_detector_controller(grid, workload, budget, *,
@@ -201,8 +198,7 @@ def run_fleet_detector_controller(grid, workload, budget, *,
         grid=grid, workload=workload, budget=budget,
         det_cfg=det_cfg, det_params=det_params, distill=distill,
         **scene_kwargs)
-    with span("engine/fleet_controller", provider="detector"):
-        return prepare_fleet_run(spec, mesh=mesh).episode()
+    return prepare_fleet_run(spec, mesh=mesh).episode()
 
 
 @partial(jax.jit, static_argnames=("k_send",))

@@ -8,14 +8,16 @@ Pins the three observability channels and their contracts:
   * the in-scan `chosen_rank`/`shortlist_hit` outputs match their host
     replay definitions (bench_rank_quality._chosen_rank; exhaustive
     shortlists always hit);
-  * span traces export well-formed Chrome trace JSON and cost nothing
-    when inactive;
+  * span traces export well-formed Chrome trace JSON on the profiler's
+    clock and always reach a profiler trace; stage scopes name every
+    stage of the detector episode in its compiled HLO;
   * the JSONL telemetry event schema round-trips and validates, both
     via the API and through `serve --fleet --telemetry -`.
 """
 import dataclasses
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -39,6 +41,7 @@ from repro.fleet import (
 )
 from repro.obs import (
     METRIC_KEYS,
+    STAGES,
     MetricsSpec,
     Tracer,
     active_tracer,
@@ -46,6 +49,8 @@ from repro.obs import (
     median_valid_rank,
     read_events,
     span,
+    stage,
+    staged,
     summarize_metrics,
     tracing,
     validate_event,
@@ -289,6 +294,83 @@ def test_tracer_non_json_args_stringified():
     with tr.span("s", arr=np.arange(3)):
         pass
     assert isinstance(tr.events[0]["args"]["arr"], str)
+
+
+def _xplane_host_events(trace_dir):
+    """(host events by name, profile_start_time) of a profiler trace."""
+    from jax.profiler import ProfileData
+
+    path = sorted(trace_dir.glob("**/*.xplane.pb"))[-1]
+    pd = ProfileData.from_file(str(path))
+    start, host = None, {}
+    for plane in pd.planes:
+        if plane.name == "Task Environment":
+            start = dict(plane.stats)["profile_start_time"]
+        if plane.name.startswith("/host:"):
+            for ln in plane.lines:
+                for e in ln.events:
+                    host.setdefault(e.name, e)
+    return host, start
+
+
+def test_profiler_trace_holds_fleet_spans(tmp_path):
+    """With no tracer active, run_fleet's host spans still reach a
+    profiler trace."""
+    assert active_tracer() is None
+    with jax.profiler.trace(str(tmp_path)):
+        _run("tables")
+    host, _ = _xplane_host_events(tmp_path)
+    assert {"fleet/build", "fleet/compile", "fleet/steady"} <= set(host)
+
+
+def test_chrome_spans_are_on_the_profilers_clock(tmp_path):
+    with jax.profiler.trace(str(tmp_path)):
+        with tracing() as tr:
+            with span("probe/sleep"):
+                time.sleep(0.02)
+    host, start = _xplane_host_events(tmp_path)
+    xp = host["probe/sleep"]
+    ev = tr.events[0]
+    # Chrome ts/dur are microseconds of the wall clock; ProfileData gives
+    # host events in ns from the trace's profile_start_time
+    assert abs(ev["ts"] * 1e3 - (start + xp.start_ns)) < 2e6
+    assert abs(ev["dur"] * 1e3 - xp.duration_ns) < 2e6
+    assert ev["dur"] >= 2e4
+
+
+def test_stage_refuses_a_name_not_in_stages():
+    for bad in ("crop", "ops/crop_patchify", "Backbone"):
+        with pytest.raises(ValueError, match="unknown stage"):
+            stage(bad)
+        with pytest.raises(ValueError, match="unknown stage"):
+            staged(bad)
+    with stage("backbone"):
+        pass
+
+
+def test_detector_episode_names_every_stage_in_its_hlo():
+    """Every stage the smoke-size detector episode runs (frozen path
+    and distillation, metrics on) names ops in the compiled HLO's
+    op_name metadata, and the crop and controller ops carry theirs."""
+    from repro.fleet.runner import _episode
+
+    spec = FleetRunSpec(provider="detector", n_cameras=2, n_steps=2,
+                        shortlist_k=6, budget={"fps": 15.0}, metrics=True,
+                        distill=True)
+    prep = prepare_fleet_run(spec)
+    hlo = _episode.lower(prep.cfg, prep.wl, prep.statics, prep.state,
+                         prep.provider, metrics=spec.metrics
+                         ).compile().as_text()
+    names = re.findall(r'\bop_name="([^"]*)"', hlo)
+
+    def first_stage(name):
+        return next((p for p in name.split("/") if p in STAGES), None)
+
+    assert {first_stage(n) for n in names} - {None} == set(STAGES)
+    crop = [n for n in names if "jit(_crop_patchify)/" in n]
+    step = [n for n in names if "jit(fleet_step)/" in n]
+    assert crop and all(first_stage(n) == "crop_to_tokens" for n in crop)
+    assert step and all(first_stage(n) == "controller" for n in step)
 
 
 # ---------------------------------------------------------------------------
