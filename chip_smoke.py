@@ -180,8 +180,10 @@ def kernel_phase(det_cfg, *, n_cameras: int = N_CAMERAS,
     pe = conv_init(jax.random.PRNGKey(seed), patch, patch, 3,
                    det_cfg.d_model)
     noise = 0.05 * rng.standard_normal((f, res, res, 3)).astype(np.float32)
-    ker = crop_patchify(pos, size, kind, oid, wins[widx], pe, patch=patch,
-                        res=res, noise=noise, use_kernel=True)
+    with jax.default_matmul_precision("highest"):
+        ker = crop_patchify(pos, size, kind, oid, wins[widx], pe,
+                            patch=patch, res=res, noise=noise,
+                            use_kernel=True)
     check(ker.shape == (f, shortlist_k, (res // patch) ** 2,
                         det_cfg.d_model), f"crop_patchify shape {ker.shape}")
     # the reference in camera slabs: its [..., 3] pixel planes pad to 128
@@ -192,7 +194,7 @@ def kernel_phase(det_cfg, *, n_cameras: int = N_CAMERAS,
         with jax.default_matmul_precision("highest"):
             refs.append(np.asarray(crop_patchify(
                 pos[sl], size[sl], kind, oid[sl], wins[widx[sl]], pe,
-                patch=patch, res=res, noise=noise[sl])))
+                patch=patch, res=res, noise=noise[sl], use_kernel=False)))
     diffs["crop_patchify"] = _compare("crop_patchify", [ker],
                                       [np.concatenate(refs)])
     return diffs

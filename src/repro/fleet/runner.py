@@ -247,9 +247,10 @@ class DetectorProvider:
         search-coupled shortlist keeps the top `shortlist_k` of the N*Z
         windows per camera (shortlist_k = N*Z reproduces exhaustive
         scoring bit-for-bit), kernels/crop_patchify turns the survivors
-        straight into patch-embedding tokens (Pallas kernel via
-        use_kernel; crops never hit HBM as pixels), and ONE batched
-        forward over the flattened [F*K] axis scores them
+        straight into patch-embedding tokens (the platform chooses: the
+        Pallas kernel on a TPU, where crops never hit HBM as pixels;
+        the jnp reference on the CPU), and ONE batched forward over the
+        flattened [F*K] axis scores them
         (engine.detector_scores_tokens).
       * fused=False — the pre-shortlist reference: every window rendered
         to pixels and scored through a serial per-chunk lax.map. Kept
@@ -276,7 +277,6 @@ class DetectorProvider:
     chunk: int                  # windows per slab (static; fused=False)
     shortlist_k: int = 0        # windows scored per camera (0 = all)
     fused: bool = True          # fast path vs reference chunk loop
-    use_kernel: bool = False    # Pallas crop_patchify vs jnp reference
     distill: object = None      # repro.learn.DistillSpec | None (static)
 
     @property
@@ -373,8 +373,7 @@ class DetectorProvider:
             patch=self.det_cfg.patch, res=self.det_cfg.img_res,
             min_visible=p.spec.min_visible, noise=noise_img,
             dtype=self.det_cfg.dtype,
-            block_k=_auto_chunk(k, self.chunk),
-            use_kernel=self.use_kernel)                     # [F, K, gg, D]
+            block_k=_auto_chunk(k, self.chunk))             # [F, K, gg, D]
         f = tokens.shape[0]
         dets = detector_scores_tokens(
             dp, self.det_cfg,
@@ -445,8 +444,7 @@ class DetectorProvider:
             patch=self.det_cfg.patch, res=self.det_cfg.img_res,
             min_visible=p.spec.min_visible, noise=noise_img,
             dtype=self.det_cfg.dtype,
-            block_k=_auto_chunk(k, self.chunk),
-            use_kernel=self.use_kernel)                     # [F, K, gg, D]
+            block_k=_auto_chunk(k, self.chunk))             # [F, K, gg, D]
         f = tokens.shape[0]
         if k == c:
             widx = jnp.broadcast_to(
@@ -541,8 +539,7 @@ jax.tree_util.register_dataclass(
     DetectorProvider,
     data_fields=["scene", "det_params", "thresh", "geo_thresh", "noise",
                  "nbr8"],
-    meta_fields=["det_cfg", "chunk", "shortlist_k", "fused", "use_kernel",
-                 "distill"])
+    meta_fields=["det_cfg", "chunk", "shortlist_k", "fused", "distill"])
 
 
 def build_episode_tables(video, workload: Workload, tables: dict,
@@ -785,7 +782,6 @@ def make_detector_provider(grid, workload: Workload, cfg: FleetConfig, *,
                            chunk: int | None = None,
                            shortlist_k: int | None = None,
                            fused: bool = True,
-                           use_kernel: bool = False,
                            distill=None, **scene_kwargs
                            ) -> tuple[DetectorProvider, FleetState]:
     """Scene provider + the approximation detector scored in-step.
@@ -809,9 +805,10 @@ def make_detector_provider(grid, workload: Workload, cfg: FleetConfig, *,
     search-coupled shortlist — must be a multiple of the zoom count;
     None/N*Z scores everything, reproducing exhaustive behavior
     bit-for-bit); `fused` picks the candidate-sparse fused pipeline
-    (default) vs the pre-shortlist chunked reference; `use_kernel`
-    dispatches the fused crop->token stage to the Pallas crop_patchify
-    kernel instead of the jnp reference.
+    (default) vs the pre-shortlist chunked reference. The fused
+    crop->token stage runs the Pallas crop_patchify kernel wherever the
+    episode is lowered for a TPU and the jnp reference on the CPU; the
+    platform chooses, not a knob.
     `chunk` bounds the reference path's render+infer slab (must divide
     N*Z, default one cell-row of zooms at a time — `_auto_chunk`).
     `scene_kwargs` are make_scene_provider's heterogeneity knobs.
@@ -895,7 +892,7 @@ def make_detector_provider(grid, workload: Workload, cfg: FleetConfig, *,
         noise=jnp.asarray(noise, jnp.float32),
         nbr8=fleet_statics(grid).neighbor8,
         chunk=chunk, shortlist_k=shortlist_k, fused=fused,
-        use_kernel=use_kernel, distill=distill)
+        distill=distill)
     return provider, state
 
 

@@ -24,6 +24,12 @@ painter wins, the same winner the reference's ownership reduction
 picks, with no [M, res, res] intermediate and no vector gather. The
 working set is a few [gg, p*p] f32 planes (~200 KB each at res 224),
 far inside the scoped VMEM.
+
+The contraction takes its operands in the weights' dtype and
+accumulates in float32. ops.py picks that dtype from the ambient matmul
+precision, so the kernel does what the convolution it replaces does: at
+the default precision bfloat16 operands (one MXU pass), otherwise
+float32 operands at HIGHEST.
 """
 from __future__ import annotations
 
@@ -45,11 +51,13 @@ def _make_kernel(n_obj: int):
                    & (cc < box_ref[0, 0, 1, m]))
             planes = [jnp.where(hit, col_ref[0, c, m], planes[c])
                       for c in range(3)]
+        exact = w_ref.dtype == jnp.float32
         tok = b_ref[...]                              # [1, D]
         for c in range(3):
-            tok = tok + jnp.dot(jnp.clip(planes[c], 0.0, 1.0), w_ref[c],
-                                precision=jax.lax.Precision.HIGHEST,
-                                preferred_element_type=jnp.float32)
+            px = jnp.clip(planes[c], 0.0, 1.0).astype(w_ref.dtype)
+            tok = tok + jnp.dot(
+                px, w_ref[c], preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST if exact else None)
         out_ref[0, 0] = tok
 
     return kernel
@@ -61,8 +69,9 @@ def crop_patchify_batch(rows, cols, boxes, colors, bgn, wsplit, bias, *,
     slot; boxes [F, K, 4, M] int32 paint rectangles (px0, px1, py0, py1
     rows; an invisible object carries an empty one); colors [F, 3, M]
     f32 paint colours; bgn [F, 3, gg, p*p] background + noise in tile
-    layout; wsplit [3, p*p, D] per-channel patch-embed weights; bias
-    [1, D]. Returns tokens [F, K, gg, D] float32."""
+    layout; wsplit [3, p*p, D] per-channel patch-embed weights in the
+    contraction's operand dtype (bfloat16, or float32 contracted at
+    HIGHEST); bias [1, D] f32. Returns tokens [F, K, gg, D] float32."""
     f, k, _, m = boxes.shape
     gg, pp = rows.shape
     d = wsplit.shape[-1]

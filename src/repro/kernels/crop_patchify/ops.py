@@ -3,13 +3,24 @@
 `crop_patchify` accepts the provider-native layout (scene object boxes +
 per-camera shortlisted FOV windows + the detector's conv patch-embed
 params) and returns the [F, K, gg, D] token rows the batched detector
-forward consumes. Like cell_rasterize, the pure-jnp reference is the
-default inside fused fleet steps — on the reference path the pixels are
-the existing `render_fleet_crops` output fed through the existing conv,
-so it is bit-identical to the unfused pixel pipeline. The Pallas kernel
-path (use_kernel=True) fuses rasterization into the patch contraction so
-crops never round-trip through HBM as pixels. It runs in the Pallas
-interpreter only on the CPU backend (repro.kernels.interpret_mode).
+forward consumes. The platform chooses the path: the Pallas kernel
+wherever the program is lowered for a TPU, the jnp reference on the CPU.
+The choice is made at lowering (`jax.lax.platform_dependent`), so a
+program compiled from a CPU process for a described TPU holds the
+kernel, as one compiled on the chip does.
+
+On the reference path the pixels are the existing `render_fleet_crops`
+output fed through the existing conv, so it is bit-identical to the
+unfused pixel pipeline. The kernel fuses rasterization into the patch
+contraction so crops never round-trip through HBM as pixels. Forcing
+one path (use_kernel=True/False) is for tests and the chip smoke run
+that compare the two; forced on the CPU, the kernel runs in the Pallas
+interpreter (repro.kernels.interpret_mode).
+
+The compiler cannot partition a Pallas call, so where the program is
+partitioned over several devices (the fleet axis on a mesh) each device
+runs the kernel on its own cameras under `shard_map`: a camera's crops
+depend on nothing of another camera's.
 """
 from __future__ import annotations
 
@@ -28,10 +39,15 @@ from repro.obs.trace import stage
 def crop_patchify(pos, size, kind, oid, windows, patch_params, *,
                   patch: int, res: int = 64, min_visible: float = 0.25,
                   noise=None, dtype=jnp.float32, block_k: int | None = None,
-                  use_kernel: bool = False) -> jnp.ndarray:
+                  use_kernel: bool | None = None) -> jnp.ndarray:
     """pos/size [F, M, 2], kind [M], oid [F, M]; windows [F, K, 4] or
     [K, 4] fleet-shared; patch_params {"w": [p, p, 3, D], "b": [D]};
     noise [F, res, res, 3] or None. Returns tokens [F, K, (res/p)^2, D].
+
+    `use_kernel` None (default): the platform chooses — the kernel
+    wherever the program is lowered for a TPU, the jnp reference on the
+    CPU. True or False forces one path. The kernel has no gradient
+    rule, so no caller differentiates through the tokens.
 
     `block_k` (reference path only; must divide K) slabs the K window
     axis so the transient pixel buffer peaks at [F, block_k, res, res,
@@ -39,8 +55,10 @@ def crop_patchify(pos, size, kind, oid, windows, patch_params, *,
     kernel's per-block VMEM residency; tokens come out identical
     because each crop's render+embed is independent. The Pallas path
     already blocks per (camera, window) and ignores it. Its patch-embed
-    contraction runs at full f32 precision whatever the ambient matmul
-    precision.
+    contraction follows the ambient matmul precision as the reference's
+    conv does on a TPU: at the default, pixels and weights rounded to
+    bfloat16 and summed in float32; at any other setting, float32 at
+    HIGHEST.
     """
     if res % patch != 0:
         raise ValueError(f"res={res} must be a multiple of patch={patch}")
@@ -59,24 +77,72 @@ def crop_patchify(pos, size, kind, oid, windows, patch_params, *,
                                    "block_k", "use_kernel", "interpret"))
 def _crop_patchify(pos, size, kind, oid, windows, patch_params, noise, *,
                    patch: int, res: int, min_visible: float, dtype,
-                   block_k: int | None, use_kernel: bool,
+                   block_k: int | None, use_kernel: bool | None,
                    interpret: bool) -> jnp.ndarray:
-    if not use_kernel:
-        ref = partial(crop_patchify_ref, pos, size, kind, oid,
-                      patch_params=patch_params, patch=patch, res=res,
-                      min_visible=min_visible, noise=noise, dtype=dtype)
-        k = windows.shape[-2]
-        if block_k is None or block_k >= k:
-            return ref(windows=windows)
-        # slab the window axis: the serial dimension only covers the
-        # cheap render+embed; callers still batch the expensive model
-        # forward over all K at once
-        wblocks = jnp.moveaxis(
-            windows.reshape(windows.shape[:-2]
-                            + (k // block_k, block_k, 4)), -3, 0)
-        tok = jax.lax.map(lambda wb: ref(windows=wb), wblocks)
-        return jnp.moveaxis(tok, 0, 1).reshape(
-            (tok.shape[1], k) + tok.shape[3:])
+    args = (pos, size, kind, oid, windows, patch_params, noise)
+    ref = partial(_reference, patch=patch, res=res, min_visible=min_visible,
+                  dtype=dtype, block_k=block_k)
+    ker = partial(_kernel, patch=patch, res=res, min_visible=min_visible,
+                  dtype=dtype)
+    if use_kernel is None:
+        return jax.lax.platform_dependent(
+            *args, tpu=partial(_per_device, partial(ker, interpret=False)),
+            default=ref)
+    return ker(*args, interpret=interpret) if use_kernel else ref(*args)
+
+
+def _per_device(ker, pos, size, kind, oid, windows, patch_params, noise):
+    """`ker` as is on one device; on a program partitioned over several,
+    each device runs it on its own cameras (the fleet axis split over
+    every axis of the mesh), since the compiler splits no Pallas call."""
+    from jax.sharding import PartitionSpec as P
+
+    mesh = getattr(jax.typeof(oid).sharding, "mesh", None)
+    args = (pos, size, kind, oid, windows, patch_params, noise)
+    if mesh is None or mesh.size <= 1:        # unsharded: an empty mesh
+        return ker(*args)
+    fleet, whole = P(mesh.axis_names), P()
+    specs = (fleet, fleet, whole, fleet,
+             fleet if windows.ndim == 3 else whole, whole,
+             whole if noise is None else fleet)
+    return jax.shard_map(ker, mesh=mesh, in_specs=specs, out_specs=fleet,
+                         check_vma=False)(*args)
+
+
+def _operand_dtype():
+    """The contraction's operand dtype under the ambient matmul precision:
+    bfloat16 for one bfloat16 pass, the default (what the TPU's
+    default-precision conv rounds to); float32 contracted at HIGHEST
+    for any other setting."""
+    prec = jax.config.jax_default_matmul_precision
+    return (jnp.bfloat16 if prec in (None, "default", "bfloat16",
+                                     "BF16_BF16_F32")
+            else jnp.float32)
+
+
+def _reference(pos, size, kind, oid, windows, patch_params, noise, *,
+               patch: int, res: int, min_visible: float, dtype,
+               block_k: int | None) -> jnp.ndarray:
+    ref = partial(crop_patchify_ref, pos, size, kind, oid,
+                  patch_params=patch_params, patch=patch, res=res,
+                  min_visible=min_visible, noise=noise, dtype=dtype)
+    k = windows.shape[-2]
+    if block_k is None or block_k >= k:
+        return ref(windows=windows)
+    # slab the window axis: the serial dimension only covers the cheap
+    # render+embed; callers still batch the expensive model forward over
+    # all K at once
+    wblocks = jnp.moveaxis(
+        windows.reshape(windows.shape[:-2] + (k // block_k, block_k, 4)),
+        -3, 0)
+    tok = jax.lax.map(lambda wb: ref(windows=wb), wblocks)
+    return jnp.moveaxis(tok, 0, 1).reshape(
+        (tok.shape[1], k) + tok.shape[3:])
+
+
+def _kernel(pos, size, kind, oid, windows, patch_params, noise, *,
+            patch: int, res: int, min_visible: float, dtype,
+            interpret: bool) -> jnp.ndarray:
     from repro.scene_jax.render import object_colors, render_background
 
     f = oid.shape[0]
@@ -100,7 +166,7 @@ def _crop_patchify(pos, size, kind, oid, windows, patch_params, noise, *,
         np.arange(patch * patch)[None, :], patch)
     rows = jnp.asarray(t[0] * patch + q[0], jnp.int32)          # [gg, p*p]
     cols = jnp.asarray(t[1] * patch + q[1], jnp.int32)
-    w = patch_params["w"].astype(jnp.float32)                   # [p,p,3,D]
+    w = patch_params["w"].astype(_operand_dtype())              # [p,p,3,D]
     wsplit = jnp.moveaxis(w, 2, 0).reshape(3, patch * patch, -1)
     bias = patch_params.get("b")
     bias = (jnp.zeros((1, w.shape[-1]), jnp.float32) if bias is None

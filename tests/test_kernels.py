@@ -269,20 +269,61 @@ def _patchify_inputs(f, m, k, d, seed=0, *, shared=False, with_noise=True):
     return pos, size, kind, oid, wins, pe, noise
 
 
-@pytest.mark.parametrize("f,m,k,d,shared",
-                         [(1, 6, 3, 8, False), (3, 22, 5, 24, False),
-                          (2, 22, 4, 16, True)])
+def _render_embed(pos, size, kind, oid, wins, pe, noise, *, operands=None):
+    """render_fleet_crops + the conv patch-embed at HIGHEST precision,
+    with the crops and weights first rounded to `operands` if given."""
+    from repro.models.layers import conv2d
+    from repro.scene_jax.render import render_fleet_crops
+
+    crops = render_fleet_crops(pos, size, kind, oid, wins, res=64,
+                               noise=noise)
+    f, k = crops.shape[:2]
+    crops, w = crops.reshape(f * k, 64, 64, 3), pe["w"]
+    if operands is not None:
+        crops = crops.astype(operands).astype(jnp.float32)
+        w = w.astype(operands).astype(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        x = conv2d(dict(pe, w=w), crops, stride=16, padding="VALID")
+    return x.reshape(f, k, -1, x.shape[-1])
+
+
+PATCHIFY_CASES = [(1, 6, 3, 8, False), (3, 22, 5, 24, False),
+                  (2, 22, 4, 16, True)]
+
+
+@pytest.mark.parametrize("f,m,k,d,shared", PATCHIFY_CASES)
 def test_crop_patchify_kernel_matches_ref(f, m, k, d, shared):
     """Pallas kernel (rasterize fused into the patch contraction, pixels
-    never materialized) == render_fleet_crops + conv patchify reference,
-    within fp32 tolerance — per-camera and fleet-shared window sets."""
+    never materialized) at the default matmul precision == render + conv
+    patch-embed with the same arithmetic (pixels and weights rounded to
+    bfloat16, products summed in float32), within fp32 tolerance —
+    per-camera and fleet-shared window sets."""
     pos, size, kind, oid, wins, pe, noise = _patchify_inputs(
         f, m, k, d, seed=f * 100 + k, shared=shared)
-    ref = crop_patchify(pos, size, kind, oid, wins, pe, patch=16, res=64,
-                        noise=noise, use_kernel=False)
+    ref = _render_embed(pos, size, kind, oid, wins, pe, noise,
+                        operands=jnp.bfloat16)
     ker = crop_patchify(pos, size, kind, oid, wins, pe, patch=16, res=64,
                         noise=noise, use_kernel=True)
     assert ref.shape == (f, k, 16, d)
+    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("f,m,k,d,shared", PATCHIFY_CASES)
+def test_crop_patchify_kernel_matches_ref_at_highest(f, m, k, d, shared):
+    """Under `jax.default_matmul_precision("highest")` the kernel
+    contracts float32 pixels and weights at HIGHEST, as the reference
+    path's conv does: both paths agree within fp32 tolerance."""
+    pos, size, kind, oid, wins, pe, noise = _patchify_inputs(
+        f, m, k, d, seed=f * 100 + k, shared=shared)
+    with jax.default_matmul_precision("highest"):
+        ref = crop_patchify(pos, size, kind, oid, wins, pe, patch=16,
+                            res=64, noise=noise, use_kernel=False)
+        ker = crop_patchify(pos, size, kind, oid, wins, pe, patch=16,
+                            res=64, noise=noise, use_kernel=True)
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(
+        _render_embed(pos, size, kind, oid, wins, pe, noise)),
+        atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -306,6 +347,29 @@ def test_crop_patchify_ref_is_render_plus_embed():
     want = conv2d(pe, crops.reshape(8, 64, 64, 3), stride=16,
                   padding="VALID").reshape(2, 4, 16, 12)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_crop_patchify_default_is_the_reference_on_cpu():
+    """Left to the platform, crop_patchify lowered for the CPU is the jnp
+    reference: bit-identical to render_fleet_crops + conv patch-embed,
+    and no kernel call in the lowered program."""
+    from repro.models.layers import conv2d
+    from repro.scene_jax.render import render_fleet_crops
+
+    pos, size, kind, oid, wins, pe, noise = _patchify_inputs(
+        2, 10, 4, 12, seed=7)
+
+    def default(*a):
+        return crop_patchify(*a, noise=noise, patch=16, res=64)
+
+    args = (pos, size, kind, oid, wins, pe)
+    assert "tpu_custom_call" not in jax.jit(default).lower(*args).as_text()
+    crops = render_fleet_crops(pos, size, kind, oid, wins, res=64,
+                               noise=noise)
+    want = conv2d(pe, crops.reshape(8, 64, 64, 3), stride=16,
+                  padding="VALID").reshape(2, 4, 16, 12)
+    np.testing.assert_array_equal(np.asarray(default(*args)),
+                                  np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
