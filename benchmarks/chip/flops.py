@@ -1,32 +1,33 @@
 """Operations the approximation detector needs, counted from its shapes.
 
-One window is one crop of `img_res` pixels: a patch embed (a conv of
-stride `patch`), `n_layers` pre-norm ViT blocks over the CLS token plus
-the patches, a 1x1 lateral and a 3x3 smooth conv (the neck) and three
-3x3 head convs. A multiply-add counts 2. Softmax, norms, activations
-and the box decode are left out: they are O(tokens x width), below 1%.
+One window is one crop of `img_res` pixels: the backbone the
+configuration names (`backbones/<name>.py`, its `window_flops`: for
+ViT, a patch embed and `n_layers` pre-norm blocks), then the shared
+part at the backbone's feature map [g, g, D] (`feature_shape`): a 1x1
+lateral and a 3x3 smooth conv (the neck) and three 3x3 head convs. A
+multiply-add counts 2. Softmax, norms, activations and the box decode
+are left out: they are O(tokens x width), below 1%.
 """
 from __future__ import annotations
 
+from benchmarks.chip import harness
 
-def detector_window_flops(d: dict) -> float:
-    """FLOPs of one forward over one window; `d` holds the config's
-    detector sizes (img_res, patch, n_layers, d_model, d_ff, fpn_dim,
-    n_classes)."""
-    g = d["img_res"] // d["patch"]
-    p = g * g                                   # patch tokens
-    t = p + 1                                   # + CLS
-    dm, ff, f = d["d_model"], d["d_ff"], d["fpn_dim"]
-    patch_embed = 2 * p * (d["patch"] ** 2 * 3) * dm
-    attn_proj = 2 * t * dm * 4 * dm             # q, k, v, out
-    attn_mix = 2 * 2 * t * t * dm               # scores and weights x V
-    mlp = 2 * 2 * t * dm * ff
-    blocks = d["n_layers"] * (attn_proj + attn_mix + mlp)
-    neck = 2 * p * dm * f + 2 * p * 9 * f * f
+
+def detector_window_flops(config: dict) -> float:
+    """FLOPs of one forward over one window of a configuration: its
+    `detector` sizes (n_classes, fpn_dim and what its backbone reads)
+    and the backbone its `backbone` key names."""
+    d = config["detector"]
+    bb = harness.load_backbone(config.get("backbone"))
+    g, _, width = bb.feature_shape(d)
+    p = g * g                                   # map cells
+    f = d["fpn_dim"]
+    neck = 2 * p * width * f + 2 * p * 9 * f * f
     heads = 2 * p * 9 * f * (d["n_classes"] + 4 + 1)
-    return float(patch_embed + blocks + neck + heads)
+    return float(bb.window_flops(d) + neck + heads)
 
 
-def detector_step_flops(d: dict, n_cameras: int, windows: int) -> float:
+def detector_step_flops(config: dict, n_cameras: int, windows: int
+                        ) -> float:
     """FLOPs of one fleet step: every camera scores `windows` windows."""
-    return detector_window_flops(d) * n_cameras * windows
+    return detector_window_flops(config) * n_cameras * windows

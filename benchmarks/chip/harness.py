@@ -4,12 +4,19 @@ A cell names a configuration (`configs/<name>.json`), a traffic mix
 (`traffic/<name>.json`) and a chip count. Metrics are readers
 (`metrics/<name>.py`, one `read(ctx)` each) and the correctness check
 is a reference module (`reference/<config["reference"]>.py`, one
-`check(run)` each). All four are found by the names in BENCHMARK.json,
-so a new cell, metric or configuration is new files, never an edit.
+`check(run)` each). A configuration with a `detector` names its
+backbone (`backbones/<config["backbone"]>.py`, `vit` where it names
+none): the weights (`reference/weights.py`), the reference forward
+(`reference/vision.py`) and the FLOP count (`flops.py`) take from it
+everything up to the neck's input, and share the neck, heads and
+decode. All are found by the names in BENCHMARK.json and the
+configuration, so a new cell, metric, configuration or backbone is new
+files, never an edit.
 
 One run:
 
-  set-up   make the detector weights on the device from the seed, let
+  set-up   make the detector weights on the device from the seed
+           (through the configuration's backbone), let
            the reference set what it derives from them (`prepare`: the
            detector's operating point), build the FleetRunSpec, AOT
            lower + compile the episode scan
@@ -76,8 +83,11 @@ def load_json(kind: str, name: str, base: Path = HERE) -> dict:
     return json.loads(path.read_text())
 
 
-def load_module(kind: str, name: str, base: Path = HERE):
-    """`metrics/<name>.py` or `reference/<name>.py` as a fresh module."""
+def load_module(kind: str, name: str, base: Path | None = None):
+    """`metrics/<name>.py`, `reference/<name>.py` or `backbones/<name>.py`
+    as a fresh module, under `base` (default: this directory, as
+    `HERE` says when called)."""
+    base = base or HERE
     path = base / kind / f"{name}.py"
     if not path.is_file():
         raise BenchError(f"no {kind} module {path.relative_to(base)}")
@@ -86,6 +96,12 @@ def load_module(kind: str, name: str, base: Path = HERE):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_backbone(name: str | None):
+    """The detector backbone a configuration's `backbone` key names
+    (`backbones/<name>.py`); `vit` where it names none."""
+    return load_module("backbones", name or "vit")
 
 
 def cell_metrics(bench: dict, cell: dict, trace: bool) -> list[dict]:
@@ -237,7 +253,7 @@ def run_cell(bench: dict, cell: dict, *, seed: int, seconds: float,
         from benchmarks.chip.reference import weights
 
         run.weights = jax.block_until_ready(
-            weights.init(config["detector"], seeds["weight_seed"]))
+            weights.init(config, seeds["weight_seed"]))
     stages["weights_s"] = time.perf_counter() - t0
     t0 = time.perf_counter()
     if hasattr(ref, "prepare"):

@@ -16,7 +16,8 @@ harness-made weights:
                      8-neighbour ring, then EWMA labels and staleness),
                      renders each explored window that was shortlisted,
                      runs the detector on it at the configuration's
-                     precision (vision.py), counts boxes per query pair
+                     precision (vision.py, through the backbone the
+                     configuration names), counts boxes per query pair
                      and scores the cells (rank.py); a cell left off
                      the shortlist counts nothing
   decision_mismatch  share of camera-steps whose explored cells, path,
@@ -56,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchmarks.chip import harness
 from benchmarks.chip.reference import controller, rank, vision, world
 
 TOL = 1e-5
@@ -133,14 +135,15 @@ def shortlists(out: dict, k_cells: int, n_pan: int, n_tilt: int):
     return lists
 
 
-@partial(jax.jit, static_argnames=("sizes", "scene_json", "dtype",
-                                   "precision"))
+@partial(jax.jit, static_argnames=("sizes", "backbone", "scene_json",
+                                   "dtype", "precision"))
 def _detect_block(params, pos, size, oid, win, cam_key, frame, noise, *,
-                  sizes, scene_json, dtype, precision):
+                  sizes, backbone, scene_json, dtype, precision):
     import json
 
     d = dict(json.loads(sizes))
     sc = json.loads(scene_json)
+    bb = harness.load_backbone(backbone)
 
     def one(pos, size, oid, win, key, frame):
         nz = vision.noise_image(key, frame, d["img_res"], noise, dtype)
@@ -148,16 +151,16 @@ def _detect_block(params, pos, size, oid, win, cam_key, frame, noise, *,
                              sc["max_people"], win.astype(dtype), nz,
                              res=d["img_res"],
                              min_visible=sc["min_visible"], dtype=dtype)
-        return vision.detect(params, crop, patch=d["patch"],
-                             n_heads=d["n_heads"], precision=precision)
+        return vision.detect(params, crop, bb, d, precision)
 
     return jax.vmap(one)(pos, size, oid, win, cam_key, frame)
 
 
 def detections(run, snap: dict, items: np.ndarray, dtype,
                precision: str | None = None) -> tuple:
-    """Reference detections for the windows `items` [(e, f, window)], at
-    the configuration's matmul precision unless `precision` is given."""
+    """Reference detections for the windows `items` [(e, f, window)], by
+    the backbone the configuration names, at its matmul precision unless
+    `precision` is given."""
     import json
 
     keys = np.asarray(world.camera_keys(run.seeds["spec_seed"],
@@ -177,7 +180,8 @@ def detections(run, snap: dict, items: np.ndarray, dtype,
             run.weights, snap["pos"][e, f], snap["size"][e, f],
             snap["oid"][e, f], wins[w], keys[f],
             (e * stride).astype(np.int32), noise, sizes=sizes,
-            scene_json=scene_json, dtype=dtype,
+            backbone=run.config.get("backbone"), scene_json=scene_json,
+            dtype=dtype,
             precision=precision or run.config["matmul_precision"])
         parts.append([np.asarray(x, np.float32)[:BLOCK - pad]
                       for x in res])

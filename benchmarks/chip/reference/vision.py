@@ -5,14 +5,16 @@ textured gradient, per-camera per-frame sensor noise, and every object
 at least `min_visible` inside the window painted as a class-coloured
 rectangle (later slots over earlier ones), clipped to [0, 1].
 
-The detector (paper sec. 3.4, a ViT backbone with an FPN-lite neck and
-anchor-free heads): 16 px patches embedded linearly, a CLS token and
-learned positions, pre-norm blocks (multi-head attention, GELU MLP),
-a final norm; the patch grid goes through a 1x1 and a 3x3 conv (GELU),
-then three 3x3 heads give class logits, box offsets and objectness. A
-cell's score is sigmoid(objectness) x its top class probability; the
-best `max_boxes` cells are the detections. Written per crop, in the
-crop's dtype and at the matmul `precision` the configuration states.
+The detector (paper sec. 3.4: a backbone with an FPN-lite neck and
+anchor-free heads): the backbone the configuration names
+(`backbones/<name>.py`, passed in as a module; the benchmark's cell
+runs `backbones/vit.py`) turns the crop into a [g, g, D] map; the
+shared part below puts it through a 1x1 and a 3x3 conv (GELU), then
+three 3x3 heads give class logits, box offsets and objectness per
+cell. A cell's score is sigmoid(objectness) x its top class
+probability; the best `max_boxes` cells are the detections. Written per
+crop, in the crop's dtype and at the matmul `precision` the
+configuration states.
 """
 from __future__ import annotations
 
@@ -74,17 +76,17 @@ def render(pos, size, oid, max_people: int, window, noise, *, res: int,
     return jnp.clip(img, 0.0, 1.0).astype(dtype)
 
 
-def _ln(p, x, eps=1e-6):
+def layer_norm(p, x, eps=1e-6):
     mu = jnp.mean(x, -1, keepdims=True)
     var = jnp.mean((x - mu) ** 2, -1, keepdims=True)
     return (x - mu) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
 
 
-def _dense(p, x, prec):
+def dense(p, x, prec):
     return jnp.matmul(x, p["w"], precision=prec) + p["b"]
 
 
-def _conv(p, x, prec):
+def conv(p, x, prec):
     """SAME conv, stride 1, of a [g, g, C] map."""
     y = jax.lax.conv_general_dilated(
         x[None], p["w"], (1, 1), "SAME",
@@ -92,50 +94,28 @@ def _conv(p, x, prec):
     return y + p["b"]
 
 
-def _gelu(x):
+def gelu(x):
     return 0.5 * x * (1.0 + jnp.tanh(0.7978845608028654
                                      * (x + 0.044715 * x ** 3)))
 
 
-def detect(params, crop, *, patch: int, n_heads: int,
+def detect(params, crop, backbone, sizes: dict,
            precision: str | None = None):
     """One crop -> every cell's (score, box cxcywh, class probs), best
-    score first; the detector keeps the first `max_boxes`. `precision`
-    is the matmul precision (None: the platform's default)."""
+    score first; the detector keeps the first `max_boxes`. `backbone` is
+    the configuration's backbone module, `sizes` its `detector` sizes,
+    `precision` the matmul precision (None: the platform's default)."""
     pr = None if precision in (None, "default") else precision
     dt = crop.dtype
     params = jax.tree.map(lambda a: a.astype(dt), params)
-    vit = params["backbone"]["vit"]
-    res = crop.shape[-1]
-    g = res // patch
-    d = vit["patch_embed"]["w"].shape[-1]
-    x = crop.reshape(3, g, patch, g, patch).transpose(1, 3, 2, 4, 0)
-    x = jnp.matmul(x.reshape(g * g, patch * patch * 3),
-                   vit["patch_embed"]["w"].reshape(-1, d), precision=pr)
-    x = x + vit["patch_embed"]["b"]
-    x = jnp.concatenate([vit["cls_token"][0], x], 0) + vit["pos_embed"][0]
-    t, dh = x.shape[0], d // n_heads
-    lay = vit["layers"]
-    for i in range(lay["norm1"]["scale"].shape[0]):
-        p = jax.tree.map(lambda a: a[i], lay)
-        h = _ln(p["norm1"], x)
-        q, k, v = (_dense(p["attn"][w], h, pr).reshape(t, n_heads, dh)
-                   for w in ("wq", "wk", "wv"))
-        logits = jnp.einsum("qhd,khd->hqk", q, k, precision=pr) \
-            / jnp.sqrt(jnp.asarray(dh, dt))
-        a = jax.nn.softmax(logits, axis=-1)
-        o = jnp.einsum("hqk,khd->qhd", a, v, precision=pr).reshape(t, d)
-        x = x + _dense(p["attn"]["wo"], o, pr)
-        h = _ln(p["norm2"], x)
-        x = x + _dense(p["mlp"]["down"],
-                       _gelu(_dense(p["mlp"]["up"], h, pr)), pr)
-    fmap = _ln(vit["final_norm"], x)[1:].reshape(g, g, d)
+    fmap = backbone.features(params["backbone"], crop, sizes, pr)
+    g = fmap.shape[0]
     neck = params["backbone"]["neck"]
-    f = _gelu(_conv(neck["smooth"], _conv(neck["lateral"], fmap, pr), pr))
+    f = gelu(conv(neck["smooth"], conv(neck["lateral"], fmap, pr), pr))
     heads = params["heads"]
-    cls = _conv(heads["cls"], f, pr).reshape(g * g, -1).astype(jnp.float32)
-    box = _conv(heads["box"], f, pr).reshape(g * g, 4).astype(jnp.float32)
-    obj = _conv(heads["obj"], f, pr).reshape(g * g).astype(jnp.float32)
+    cls = conv(heads["cls"], f, pr).reshape(g * g, -1).astype(jnp.float32)
+    box = conv(heads["box"], f, pr).reshape(g * g, 4).astype(jnp.float32)
+    obj = conv(heads["obj"], f, pr).reshape(g * g).astype(jnp.float32)
 
     ys, xs = jnp.meshgrid(jnp.arange(g), jnp.arange(g), indexing="ij")
     off = jax.nn.sigmoid(box[:, :2])

@@ -26,6 +26,8 @@ def test_every_cell_finds_its_files_by_name():
         assert config["name"] == cell["config"]
         assert traffic["n_steps"] > 0
         harness.load_module("reference", config["reference"])
+        if "detector" in config:
+            harness.load_backbone(config.get("backbone"))
         for trace in (False, True):
             for m in harness.cell_metrics(bench, cell, trace):
                 assert callable(harness.load_module("metrics",
@@ -150,8 +152,11 @@ def test_union_merges_overlaps():
 # ---------------------------------------------------------------------------
 
 def test_detector_flops_at_full_width_by_hand():
+    """The count through the ViT backbone (`backbones/vit.py`, which the
+    configuration names by leaving `backbone` out) and the shared neck
+    and heads."""
     d = json.loads((BENCH / "configs" / "madeye-approx.json").read_text())
-    sizes = d["detector"]
+    assert "backbone" not in d
     # DeiT-Ti: 196 patches + CLS, d 192, MLP 768, 12 blocks; FPN 128,
     # 2 classes
     patch_embed = 2 * 196 * 768 * 192
@@ -161,9 +166,10 @@ def test_detector_flops_at_full_width_by_hand():
     neck = 2 * 196 * 192 * 128 + 2 * 196 * 9 * 128 * 128
     heads = 2 * 196 * 9 * 128 * 7
     hand = patch_embed + 12 * block + neck + heads
-    assert flops.detector_window_flops(sizes) == hand
-    assert 2.5e9 < hand < 2.6e9
-    assert flops.detector_step_flops(sizes, 64, 18) == hand * 64 * 18
+    assert hand == 2_577_580_032
+    assert flops.detector_window_flops(d) == hand
+    assert flops.detector_window_flops(dict(d, backbone="vit")) == hand
+    assert flops.detector_step_flops(d, 64, 18) == hand * 64 * 18
 
 
 def test_peak_table_refuses_an_unknown_device():
