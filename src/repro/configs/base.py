@@ -104,8 +104,14 @@ class DiffusionConfig:
 
 @dataclass(frozen=True)
 class DetectorConfig:
-    """MadEye approximation model: light ViT backbone + anchor-free det
-    heads."""
+    """MadEye approximation model: a light transformer backbone +
+    anchor-free det heads.
+
+    `backbone` "vit": `n_layers` blocks at `d_model` with `n_heads`
+    heads over `patch` px patches. "swin": stages of `depths` blocks
+    over `patch` px patches, `d_model` / `n_heads` / `d_ff` those of the
+    first stage (each later stage doubles all three), attention in
+    `window` x `window` windows; `n_layers` is sum(depths)."""
     name: str
     img_res: int
     patch: int
@@ -118,6 +124,32 @@ class DetectorConfig:
     fpn_dim: int = 128
     dtype: Any = jnp.float32
     remat: bool = False
+    backbone: str = "vit"
+    depths: tuple = ()               # swin: blocks per stage
+    window: int = 7                  # swin: attention window side
+
+    def __post_init__(self):
+        # a JSON configuration gives lists; a jit-static config must hash
+        object.__setattr__(self, "depths", tuple(self.depths))
+        if self.backbone not in ("vit", "swin"):
+            raise ValueError(f"unknown detector backbone "
+                             f"{self.backbone!r}; known: vit, swin")
+        if self.backbone == "swin" and sum(self.depths) != self.n_layers:
+            raise ValueError(f"swin n_layers={self.n_layers} must be "
+                             f"sum(depths)={sum(self.depths)}")
+
+    @property
+    def token_grid(self) -> int:
+        """Side of the patch-token grid the backbone takes."""
+        return self.img_res // self.patch
+
+    @property
+    def feature_grid(self) -> int:
+        """Side of the map the neck and heads see: the token grid (ViT),
+        or Swin's second-to-last stage, which the last is merged onto."""
+        if self.backbone == "swin":
+            return self.token_grid >> (len(self.depths) - 2)
+        return self.token_grid
 
     @property
     def family(self) -> str:

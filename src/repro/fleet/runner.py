@@ -32,8 +32,8 @@ step consumes. Three providers ship in the registry:
     is candidate-sparse and fused: a search-coupled shortlist keeps the
     `shortlist_k` windows reachable by the shape search / top-EWMA
     cells, kernels/crop_patchify rasterizes the survivors directly into
-    ViT patch embeddings, and one batched forward over the flattened
-    [F*K] axis scores them (shortlist_k = N*Z is exhaustive and
+    the backbone's patch embeddings, and one batched forward over the
+    flattened [F*K] axis scores them (shortlist_k = N*Z is exhaustive and
     bit-identical to the retained fused=False chunked reference).
     Detector params ride in the scan carry so a future in-scan
     distillation step can update them; render noise keys fold from the
@@ -357,6 +357,7 @@ class DetectorProvider:
         """Shortlist -> fused crop->token kernel -> one [F*K] forward,
         detections scattered back to the full window axis."""
         from repro.kernels.crop_patchify.ops import crop_patchify
+        from repro.models.detector import patch_embed
         from repro.serving.engine import detector_scores_tokens
 
         p = self.scene
@@ -369,7 +370,7 @@ class DetectorProvider:
             wins = p.windows                                # shared [C, 4]
         tokens = crop_patchify(
             sc.pos, sc.size, kinds, sc.oid, wins,
-            dp["backbone"]["vit"]["patch_embed"],
+            patch_embed(dp),
             patch=self.det_cfg.patch, res=self.det_cfg.img_res,
             min_visible=p.spec.min_visible, noise=noise_img,
             dtype=self.det_cfg.dtype,
@@ -428,6 +429,7 @@ class DetectorProvider:
             detections_from_feats,
             detector_forward_tokens,
             detector_neck_feats_tokens,
+            patch_embed,
         )
 
         p = self.scene
@@ -440,7 +442,7 @@ class DetectorProvider:
             wins = p.windows                                # shared [C, 4]
         tokens = crop_patchify(
             sc.pos, sc.size, kinds, sc.oid, wins,
-            dp["backbone"]["vit"]["patch_embed"],
+            patch_embed(dp),
             patch=self.det_cfg.patch, res=self.det_cfg.img_res,
             min_visible=p.spec.min_visible, noise=noise_img,
             dtype=self.det_cfg.dtype,

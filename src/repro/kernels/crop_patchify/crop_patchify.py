@@ -15,7 +15,10 @@ written out.
 The crop is painted directly in patch-tile layout: one [gg, p*p] plane
 per colour channel, row t = patch (gy, gx), column q = pixel (py, px)
 inside the patch, so the patch-embed is three [gg, p*p] x [p*p, D]
-matmuls and no in-kernel reshape or transpose is needed. ops.py hands in
+matmuls and no in-kernel reshape or transpose is needed. Where a patch
+has fewer pixels than a row has lanes, ops.py hands in planes of r
+patches a row ([gg/r, r*p*p]) and weights with r copies on the
+diagonal ([r*p*p, r*D]); the kernel is the same. ops.py hands in
 the pixel row/column of every (t, q) slot, the background+noise plane
 already in that layout, and the per-(window, object) paint rectangles
 and colours as scalars in SMEM. Painting walks the objects in slot

@@ -17,6 +17,11 @@ one path (use_kernel=True/False) is for tests and the chip smoke run
 that compare the two; forced on the CPU, the kernel runs in the Pallas
 interpreter (repro.kernels.interpret_mode).
 
+Where a patch has fewer pixels than a vector register has lanes (patch
+4: 16 pixels), the kernel paints several patches side by side in one
+plane row and contracts them against block-diagonal weights
+(`pack_factor`); its rows are then the tokens in row-major order.
+
 The compiler cannot partition a Pallas call, so where the program is
 partitioned over several devices (the fleet axis on a mesh) each device
 runs the kernel on its own cameras under `shard_map`: a camera's crops
@@ -171,6 +176,32 @@ def _kernel(pos, size, kind, oid, windows, patch_params, noise, *,
     bias = patch_params.get("b")
     bias = (jnp.zeros((1, w.shape[-1]), jnp.float32) if bias is None
             else bias.astype(jnp.float32)[None])
+    r = pack_factor(g * g, patch * patch)
+    if r > 1:
+        # r patches a plane row: [gg, p*p] -> [gg/r, r*p*p] in row-major
+        # order, contracted against r copies of the weights on the
+        # diagonal, so that the [gg/r, r*D] rows are the [gg, D] tokens
+        d = wsplit.shape[-1]
+        rows, cols = (a.reshape(g * g // r, r * patch * patch)
+                      for a in (rows, cols))
+        bgn = bgn.reshape(f, 3, g * g // r, r * patch * patch)
+        eye = jnp.eye(r, dtype=wsplit.dtype)
+        wsplit = jnp.einsum("ab,cqd->caqbd", eye, wsplit).reshape(
+            3, r * patch * patch, r * d)
+        bias = jnp.tile(bias, (1, r))
     tok = crop_patchify_batch(rows, cols, boxes, colors, bgn, wsplit, bias,
                               interpret=interpret)
+    if r > 1:
+        tok = tok.reshape(tok.shape[:2] + (g * g, -1))
     return tok.astype(dtype)
+
+
+def pack_factor(n_patches: int, patch_pixels: int, lanes: int = 128) -> int:
+    """Patches the kernel paints side by side in one plane row: as many
+    as fill the `lanes` of a vector register (1 where one patch does,
+    as at patch 16), and a divisor of the patch count. At patch 4 a
+    patch is 16 pixels, so 8 share a row and no lane is left empty."""
+    r = max(1, lanes // patch_pixels)
+    while n_patches % r:
+        r -= 1
+    return r

@@ -60,8 +60,9 @@ def trainable_mask(dspec: DistillSpec, trainable) -> Any:
     if dspec.head_only:
         return jax.tree.map(lambda _: True, trainable)
     m = jax.tree.map(lambda _: True, trainable)
-    m["backbone"]["vit"]["patch_embed"] = jax.tree.map(
-        lambda _: False, trainable["backbone"]["vit"]["patch_embed"])
+    name = det.backbone_name(trainable)
+    m["backbone"][name]["patch_embed"] = jax.tree.map(
+        lambda _: False, det.patch_embed(trainable))
     return m
 
 
@@ -70,7 +71,6 @@ def init_learn(dspec: DistillSpec, det_cfg, det_params, n_cameras: int,
     """Broadcast the trainable subtree per camera and size the ring +
     staging buffers. Runs inside jit (init_carry)."""
     f = n_cameras
-    g = det_cfg.img_res // det_cfg.patch
     sub = det_params["heads"] if dspec.head_only else det_params
     params = jax.tree.map(
         lambda p: jnp.broadcast_to(p[None], (f,) + p.shape), sub)
@@ -80,9 +80,10 @@ def init_learn(dspec: DistillSpec, det_cfg, det_params, n_cameras: int,
     else:
         opt = optim.sgd_init(params)
     if dspec.head_only:
+        g = det_cfg.feature_grid
         payload = (g, g, det_cfg.fpn_dim)
     else:
-        payload = (g * g, det_cfg.d_model)
+        payload = (det_cfg.token_grid ** 2, det_cfg.d_model)
     return LearnState(
         params=params, opt=opt,
         buf=init_pair_buffer(f, dspec.buffer, payload, det_cfg.max_boxes,

@@ -295,19 +295,20 @@ def shifted_window_mask(H: int, W: int, window: int,
 
 def window_attention(p: Params, x: jnp.ndarray, *, n_heads: int,
                      rel_bias: jnp.ndarray | None = None,
-                     mask: jnp.ndarray | None = None,
-                     impl: str = "xla") -> jnp.ndarray:
-    """x: [nWB, T, C] windows; rel_bias: [n_heads, T, T]; mask: [nW, T, T]."""
+                     mask: jnp.ndarray | None = None) -> jnp.ndarray:
+    """x: [nWB, T, C] windows, each image's nW windows together (the
+    `window_partition` order); rel_bias: [n_heads, T, T]; mask: [nW, T,
+    T], the same for every image, so it broadcasts and is never tiled
+    over the batch."""
     nWB, T, C = x.shape
-    q = linear(p["wq"], x).reshape(nWB, T, n_heads, -1)
-    k = linear(p["wk"], x).reshape(nWB, T, n_heads, -1)
-    v = linear(p["wv"], x).reshape(nWB, T, n_heads, -1)
-    bias = None
+    nW = 1 if mask is None else mask.shape[0]
+    q, k, v = (linear(p[w], x).reshape(nWB // nW, nW, T, n_heads, -1)
+               .astype(jnp.float32) for w in ("wq", "wk", "wv"))
+    logits = jnp.einsum("bwqhd,bwkhd->bwhqk", q, k) / math.sqrt(q.shape[-1])
     if rel_bias is not None:
-        bias = rel_bias[None, :, None]  # [1, H, 1, T, T] (g axis broadcast)
+        logits = logits + rel_bias.astype(jnp.float32)
     if mask is not None:
-        nW = mask.shape[0]
-        m = jnp.tile(mask, (nWB // nW, 1, 1))[:, None, None]  # [nWB,1,1,T,T]
-        bias = m if bias is None else bias + m
-    o = sdpa_xla(q, k, v, causal=False, bias=bias)
+        logits = logits + mask.astype(jnp.float32)[:, None]
+    w = jax.nn.softmax(logits, axis=-1)
+    o = jnp.einsum("bwhqk,bwkhd->bwqhd", w, v).astype(x.dtype)
     return linear(p["wo"], o.reshape(nWB, T, C))

@@ -4,8 +4,10 @@ Paper §3.1: an ultra-lightweight detector for objects of interest, one per
 query, used ONLY to rank orientations. Design choices mirrored here:
 
   * frozen feature extractor shared across queries (paper: EfficientDet
-    backbone + BiFPN frozen, pre-trained on VOC) -> here: a small ViT
-    backbone + FPN-lite neck whose params sit under ``params["backbone"]``
+    backbone + BiFPN frozen, pre-trained on VOC) -> here: a small
+    transformer backbone (ViT, or Swin's last two stages on one grid;
+    `DetectorConfig.backbone`) + FPN-lite neck whose params sit under
+    ``params["backbone"]``
     and are excluded from fine-tuning via `lax.stop_gradient` + optimizer
     masking (train/optim.py);
   * only the final box/class/centerness heads are per-query fine-tuned
@@ -25,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import DetectorConfig, VisionConfig
-from repro.models import vit
+from repro.models import swin, vit
 from repro.models.layers import Params, conv2d, conv_init
 from repro.obs.trace import stage, staged
 
@@ -43,17 +45,57 @@ def _backbone_cfg(cfg: DetectorConfig) -> VisionConfig:
         d_ff=cfg.d_ff, n_classes=2, dtype=cfg.dtype)
 
 
+# ---------------------------------------------------------------------------
+# The backbone, chosen here and nowhere else: everything from the neck's
+# input on is one code path
+# ---------------------------------------------------------------------------
+
+def _backbone_init(key, cfg: DetectorConfig) -> Params:
+    if cfg.backbone == "swin":
+        return swin.swin_backbone_init(key, cfg)
+    return vit.vit_init(key, _backbone_cfg(cfg))
+
+
+def _feature_width(cfg: DetectorConfig) -> int:
+    if cfg.backbone == "swin":
+        return swin.swin_feature_width(cfg)
+    return cfg.d_model
+
+
+def _features_tokens(bb: Params, cfg: DetectorConfig,
+                     tokens: jnp.ndarray) -> jnp.ndarray:
+    """patch tokens [B, P, D] -> the backbone's map [B, g, g, width]
+    (g = cfg.feature_grid). Callers open the `backbone` stage."""
+    if cfg.backbone == "swin":
+        return swin.swin_features_tokens(bb["swin"], cfg, tokens)
+    return vit.vit_features_tokens(bb["vit"], _backbone_cfg(cfg), tokens)
+
+
+def backbone_name(params: Params) -> str:
+    """The backbone's entry of `params["backbone"]` (the one beside the
+    neck): "vit" or "swin"."""
+    (name,) = (k for k in params["backbone"] if k != "neck")
+    return name
+
+
+def patch_embed(params: Params) -> Params:
+    """The token path's patch-embedding params ({"w": [p, p, 3, D],
+    "b": [D]}), whichever backbone the detector has: what
+    kernels/crop_patchify contracts the crops with."""
+    return params["backbone"][backbone_name(params)]["patch_embed"]
+
+
 def detector_init(key, cfg: DetectorConfig) -> Params:
     kb, kn, kh1, kh2, kh3 = jax.random.split(key, 5)
-    bcfg = _backbone_cfg(cfg)
     F = cfg.fpn_dim
     return {
         # ---- frozen across queries (cached on cameras) ----
         "backbone": {
-            "vit": vit.vit_init(kb, bcfg),
+            cfg.backbone: _backbone_init(kb, cfg),
             "neck": {
                 "lateral": conv_init(jax.random.fold_in(kn, 0), 1, 1,
-                                     cfg.d_model, F, dtype=cfg.dtype),
+                                     _feature_width(cfg), F,
+                                     dtype=cfg.dtype),
                 "smooth": conv_init(jax.random.fold_in(kn, 1), 3, 3, F, F,
                                     dtype=cfg.dtype),
             },
@@ -102,12 +144,12 @@ def detector_raw(params: Params, cfg: DetectorConfig, images: jnp.ndarray, *,
     Box parametrization: sigmoid(dx,dy) = center offset inside the cell,
     sigmoid(w,h) = size relative to the whole image.
     """
-    bcfg = _backbone_cfg(cfg)
     bb = params["backbone"]
     if freeze_backbone:
         bb = jax.lax.stop_gradient(bb)
     with stage("backbone"):
-        feats = vit.vit_features(bb["vit"], bcfg, images)  # [B, g, g, D]
+        tokens = vit.vit_embed(bb[cfg.backbone], _backbone_cfg(cfg), images)
+        feats = _features_tokens(bb, cfg, tokens)    # [B, g, g, D]
     return _neck_and_heads(params, bb, feats)
 
 
@@ -117,12 +159,11 @@ def detector_raw_tokens(params: Params, cfg: DetectorConfig,
     """Patch-embedding tokens [B, P, D] (vit.vit_embed layout — e.g. the
     fused kernels/crop_patchify output) -> the same raw head outputs as
     `detector_raw` on the images those tokens embed."""
-    bcfg = _backbone_cfg(cfg)
     bb = params["backbone"]
     if freeze_backbone:
         bb = jax.lax.stop_gradient(bb)
     with stage("backbone"):
-        feats = vit.vit_features_tokens(bb["vit"], bcfg, tokens)
+        feats = _features_tokens(bb, cfg, tokens)
     return _neck_and_heads(params, bb, feats)
 
 
@@ -135,10 +176,9 @@ def detector_neck_feats_tokens(params: Params, cfg: DetectorConfig,
     the [F*K] shortlist, per-camera heads consume the result, and the
     same features are staged as the training payload — head-only
     distillation re-runs zero backbone compute."""
-    bcfg = _backbone_cfg(cfg)
     bb = params["backbone"]
     with stage("backbone"):
-        feats = vit.vit_features_tokens(bb["vit"], bcfg, tokens)
+        feats = _features_tokens(bb, cfg, tokens)
     return neck_features(bb, feats)
 
 

@@ -7,8 +7,9 @@ Three layers, one per kind of blindness the fleet pipeline had:
               rank, EWMA labels, budget counters) — per-step [E, F]
               device outputs, zero cost when off
   trace.py    the step's stage names (`STAGES`, `stage` named scopes,
-              read from a device trace) and host spans (build / compile
-              / steady-state / bench-leg phases) that always reach the
+              read from a device trace; `SUBSTAGES` inside `backbone`)
+              and host spans (build / compile / steady-state /
+              bench-leg phases) that always reach the
               profiler's trace, optionally recorded as Chrome trace JSON
   events.py   FleetResult -> chunked JSONL event stream with per-camera
               health summaries (`serve --fleet N --telemetry PATH|-`)
@@ -26,6 +27,7 @@ from repro.obs.metrics import (
 )
 from repro.obs.trace import (
     STAGES,
+    SUBSTAGES,
     Tracer,
     activate,
     active_tracer,
@@ -33,6 +35,7 @@ from repro.obs.trace import (
     span,
     stage,
     staged,
+    substage,
     tracing,
 )
 from repro.obs.events import (
@@ -50,6 +53,7 @@ __all__ = [
     "step_metrics",
     "summarize_metrics",
     "STAGES",
+    "SUBSTAGES",
     "Tracer",
     "activate",
     "active_tracer",
@@ -57,6 +61,7 @@ __all__ = [
     "span",
     "stage",
     "staged",
+    "substage",
     "tracing",
     "SCHEMA_VERSION",
     "episode_events",

@@ -7,7 +7,10 @@ Two kinds of name, one per side of the dispatch:
     `jax.named_scope` for one. A scope changes only the `op_name`
     metadata of the ops traced inside it, never the compiled ops, so
     it costs nothing at run time; a profiler trace of the chip, read
-    with that metadata, gives each stage's device time.
+    with that metadata, gives each stage's device time. Inside the
+    `backbone` stage, `substage(name)` names one of `SUBSTAGES` (Swin's
+    window attention and patch merging); a reader by stage still sees
+    `backbone`, the first stage on the op's path.
   * host spans — `span(name)` always opens a
     `jax.profiler.TraceAnnotation`, so any `jax.profiler.trace` of the
     program shows its host phases beside the device ops. With a
@@ -56,6 +59,20 @@ def stage(name: str):
     if name not in STAGES:
         raise ValueError(f"unknown stage {name!r}; STAGES: "
                          f"{', '.join(STAGES)}")
+    return jax.named_scope(name)
+
+
+# Parts of the `backbone` stage, read on their own. Never stages: an
+# op's stage stays the first component of its path that is in STAGES.
+SUBSTAGES = ("window_attention", "patch_merge")
+
+
+def substage(name: str):
+    """`jax.named_scope(name)` for one of `SUBSTAGES`, opened inside
+    `stage("backbone")`; any other name is refused."""
+    if name not in SUBSTAGES:
+        raise ValueError(f"unknown substage {name!r}; SUBSTAGES: "
+                         f"{', '.join(SUBSTAGES)}")
     return jax.named_scope(name)
 
 

@@ -243,7 +243,8 @@ def test_cell_rasterize_ref_matches_gt_boxes():
 # crop patchify (fused rasterize -> ViT patch-embed, detector fast path)
 # ---------------------------------------------------------------------------
 
-def _patchify_inputs(f, m, k, d, seed=0, *, shared=False, with_noise=True):
+def _patchify_inputs(f, m, k, d, seed=0, *, shared=False, with_noise=True,
+                     patch=16):
     """Random scene boxes + per-camera window subsets + patch-embed
     params, shaped like the detector provider's fast path."""
     from repro.core.grid import DEFAULT_GRID
@@ -262,14 +263,15 @@ def _patchify_inputs(f, m, k, d, seed=0, *, shared=False, with_noise=True):
         widx = np.stack([rng.choice(wins_all.shape[0], k, replace=False)
                          for _ in range(f)])
         wins = wins_all[jnp.asarray(widx)]
-    pe = conv_init(jax.random.fold_in(KEY, seed), 16, 16, 3, d)
+    pe = conv_init(jax.random.fold_in(KEY, seed), patch, patch, 3, d)
     noise = (0.05 * jax.random.normal(jax.random.fold_in(KEY, seed + 1),
                                       (f, 64, 64, 3))
              if with_noise else None)
     return pos, size, kind, oid, wins, pe, noise
 
 
-def _render_embed(pos, size, kind, oid, wins, pe, noise, *, operands=None):
+def _render_embed(pos, size, kind, oid, wins, pe, noise, *, operands=None,
+                  patch=16):
     """render_fleet_crops + the conv patch-embed at HIGHEST precision,
     with the crops and weights first rounded to `operands` if given."""
     from repro.models.layers import conv2d
@@ -283,49 +285,85 @@ def _render_embed(pos, size, kind, oid, wins, pe, noise, *, operands=None):
         crops = crops.astype(operands).astype(jnp.float32)
         w = w.astype(operands).astype(jnp.float32)
     with jax.default_matmul_precision("highest"):
-        x = conv2d(dict(pe, w=w), crops, stride=16, padding="VALID")
+        x = conv2d(dict(pe, w=w), crops, stride=patch, padding="VALID")
     return x.reshape(f, k, -1, x.shape[-1])
 
 
-PATCHIFY_CASES = [(1, 6, 3, 8, False), (3, 22, 5, 24, False),
-                  (2, 22, 4, 16, True)]
+_CASES = [(1, 6, 3, 8, False), (3, 22, 5, 24, False), (2, 22, 4, 16, True)]
+# patch 16 (the ViT's; the ids these cases always had) and patch 4 at
+# Swin-T's width 96, where the kernel paints 8 patches a plane row
+PATCHIFY_CASES = (
+    [pytest.param(16, *c, id="-".join(map(str, c))) for c in _CASES]
+    + [pytest.param(4, f, m, k, 96, sh, id=f"p4-{f}-{m}-{k}-96-{sh}")
+       for f, m, k, _, sh in _CASES])
 
 
-@pytest.mark.parametrize("f,m,k,d,shared", PATCHIFY_CASES)
-def test_crop_patchify_kernel_matches_ref(f, m, k, d, shared):
+@pytest.mark.parametrize("patch,f,m,k,d,shared", PATCHIFY_CASES)
+def test_crop_patchify_kernel_matches_ref(patch, f, m, k, d, shared):
     """Pallas kernel (rasterize fused into the patch contraction, pixels
     never materialized) at the default matmul precision == render + conv
     patch-embed with the same arithmetic (pixels and weights rounded to
     bfloat16, products summed in float32), within fp32 tolerance —
-    per-camera and fleet-shared window sets."""
+    per-camera and fleet-shared window sets, at patch 16 and 4."""
     pos, size, kind, oid, wins, pe, noise = _patchify_inputs(
-        f, m, k, d, seed=f * 100 + k, shared=shared)
+        f, m, k, d, seed=f * 100 + k, shared=shared, patch=patch)
     ref = _render_embed(pos, size, kind, oid, wins, pe, noise,
-                        operands=jnp.bfloat16)
-    ker = crop_patchify(pos, size, kind, oid, wins, pe, patch=16, res=64,
-                        noise=noise, use_kernel=True)
-    assert ref.shape == (f, k, 16, d)
+                        operands=jnp.bfloat16, patch=patch)
+    ker = crop_patchify(pos, size, kind, oid, wins, pe, patch=patch,
+                        res=64, noise=noise, use_kernel=True)
+    assert ref.shape == (f, k, (64 // patch) ** 2, d)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("f,m,k,d,shared", PATCHIFY_CASES)
-def test_crop_patchify_kernel_matches_ref_at_highest(f, m, k, d, shared):
+@pytest.mark.parametrize("patch,f,m,k,d,shared", PATCHIFY_CASES)
+def test_crop_patchify_kernel_matches_ref_at_highest(patch, f, m, k, d,
+                                                     shared):
     """Under `jax.default_matmul_precision("highest")` the kernel
     contracts float32 pixels and weights at HIGHEST, as the reference
     path's conv does: both paths agree within fp32 tolerance."""
     pos, size, kind, oid, wins, pe, noise = _patchify_inputs(
-        f, m, k, d, seed=f * 100 + k, shared=shared)
+        f, m, k, d, seed=f * 100 + k, shared=shared, patch=patch)
     with jax.default_matmul_precision("highest"):
-        ref = crop_patchify(pos, size, kind, oid, wins, pe, patch=16,
+        ref = crop_patchify(pos, size, kind, oid, wins, pe, patch=patch,
                             res=64, noise=noise, use_kernel=False)
-        ker = crop_patchify(pos, size, kind, oid, wins, pe, patch=16,
+        ker = crop_patchify(pos, size, kind, oid, wins, pe, patch=patch,
                             res=64, noise=noise, use_kernel=True)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(
-        _render_embed(pos, size, kind, oid, wins, pe, noise)),
+        _render_embed(pos, size, kind, oid, wins, pe, noise, patch=patch)),
         atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+def test_crop_patchify_packs_small_patches_only(monkeypatch):
+    """Patches share a plane row only where one leaves lanes empty: at
+    patch 16 (256 pixels) the kernel gets the [gg, p*p] planes it always
+    got; at patch 4 eight 16-pixel patches fill 128 lanes."""
+    import repro.kernels.crop_patchify.ops as ops_mod
+
+    assert ops_mod.pack_factor(196, 256) == 1
+    assert ops_mod.pack_factor(16, 256) == 1
+    assert ops_mod.pack_factor(3136, 16) == 8
+    assert ops_mod.pack_factor(256, 16) == 8
+    assert ops_mod.pack_factor(784, 64) == 2
+    assert ops_mod.pack_factor(9, 16) == 3
+
+    seen = []
+    batch = ops_mod.crop_patchify_batch
+
+    def spy(rows, *a, **kw):
+        seen.append(rows.shape)
+        return batch(rows, *a, **kw)
+
+    monkeypatch.setattr(ops_mod, "crop_patchify_batch", spy)
+    for patch, d in ((16, 12), (4, 96)):
+        pos, size, kind, oid, wins, pe, noise = _patchify_inputs(
+            2, 10, 3, d, seed=5, patch=patch)
+        got = crop_patchify(pos, size, kind, oid, wins, pe, patch=patch,
+                            res=64, noise=noise, use_kernel=True)
+        assert got.shape == (2, 3, (64 // patch) ** 2, d)
+    assert seen == [(16, 256), (32, 128)]
 
 
 def test_crop_patchify_ref_is_render_plus_embed():

@@ -121,6 +121,42 @@ def test_detector_smoke():
     assert bool(jnp.all((d.scores >= 0) & (d.scores <= 1)))
 
 
+def test_swin_detector_smoke():
+    """The Swin-backed detector: image and token entries give the same
+    detections, on the 4 x 4 head grid of the ViT smoke."""
+    from repro.models import detector as det
+    from repro.models import vit as vit_mod
+    cfg = get_smoke_config("madeye-approx-swin-t")
+    assert (cfg.backbone, cfg.token_grid, cfg.feature_grid) == ("swin", 16, 4)
+    params = det.detector_init(KEY, cfg)
+    assert det.backbone_name(params) == "swin"
+    img = jax.random.uniform(KEY, (2, cfg.img_res, cfg.img_res, 3))
+    d = det.detector_forward(params, cfg, img)
+    tokens = vit_mod.vit_embed(params["backbone"]["swin"],
+                               det._backbone_cfg(cfg), img)
+    dt = det.detector_forward_tokens(params, cfg, tokens)
+    assert d.scores.shape == (2, cfg.max_boxes)
+    np.testing.assert_array_equal(np.asarray(d.scores),
+                                  np.asarray(dt.scores))
+    assert bool(jnp.all((d.scores >= 0) & (d.scores <= 1)))
+
+
+def test_swin_b_smoke_initialises_and_runs():
+    """The Swin-B classifier shares the detector's block: its smoke size
+    still initialises and runs, with relative-bias tables of (2w-1)^2
+    rows as published."""
+    from repro.models import swin as swin_mod
+    cfg = get_smoke_config("swin-b")
+    params = swin_mod.swin_init(KEY, cfg)
+    for stage in params["stages"]:
+        for block in stage["blocks"]:
+            assert block["rel_bias"].shape[0] == (2 * cfg.window - 1) ** 2
+    img = jax.random.normal(KEY, (2, cfg.img_res, cfg.img_res, 3))
+    logits = swin_mod.swin_forward(params, cfg, img)
+    assert logits.shape == (2, cfg.n_classes)
+    assert bool(jnp.isfinite(logits).all())
+
+
 def test_diffusion_samplers_run():
     from repro.models import dit as dit_mod
     from repro.models import diffusion as diff

@@ -42,6 +42,7 @@ from repro.fleet import (
 from repro.obs import (
     METRIC_KEYS,
     STAGES,
+    SUBSTAGES,
     MetricsSpec,
     Tracer,
     active_tracer,
@@ -51,6 +52,7 @@ from repro.obs import (
     span,
     stage,
     staged,
+    substage,
     summarize_metrics,
     tracing,
     validate_event,
@@ -371,6 +373,50 @@ def test_detector_episode_names_every_stage_in_its_hlo():
     step = [n for n in names if "jit(fleet_step)/" in n]
     assert crop and all(first_stage(n) == "crop_to_tokens" for n in crop)
     assert step and all(first_stage(n) == "controller" for n in step)
+
+
+def test_substage_refuses_a_name_not_in_substages():
+    """Substages are their own names, never stages: the stage readers'
+    list stays the nine stages of the step."""
+    assert STAGES == ("scene_advance", "oracle_grade", "shortlist",
+                      "crop_to_tokens", "backbone", "heads", "controller",
+                      "distill", "telemetry")
+    assert SUBSTAGES == ("window_attention", "patch_merge")
+    assert not set(SUBSTAGES) & set(STAGES)
+    for bad in ("backbone", "attention", "Window_attention"):
+        with pytest.raises(ValueError, match="unknown substage"):
+            substage(bad)
+    with stage("backbone"), substage("window_attention"):
+        pass
+
+
+def test_swin_detector_episode_names_its_substages_in_the_backbone():
+    """The smoke Swin detector's episode: its window attention and patch
+    merge ops carry `backbone/.../window_attention` and
+    `backbone/.../patch_merge`, so a stage reader still counts them in
+    `backbone`, and every stage of the frozen path is named."""
+    from repro.configs import get_smoke_config
+    from repro.fleet.runner import _episode
+
+    cfg = get_smoke_config("madeye-approx-swin-t")
+    spec = FleetRunSpec(provider="detector", n_cameras=2, n_steps=2,
+                        shortlist_k=6, budget={"fps": 15.0},
+                        provider_kwargs={"det_cfg": cfg})
+    prep = prepare_fleet_run(spec)
+    hlo = _episode.lower(prep.cfg, prep.wl, prep.statics, prep.state,
+                         prep.provider, metrics=None).compile().as_text()
+    names = re.findall(r'\bop_name="([^"]*)"', hlo)
+
+    def first_stage(name):
+        return next((p for p in name.split("/") if p in STAGES), None)
+
+    for sub in SUBSTAGES:
+        ops = [n.split("/") for n in names if sub in n.split("/")]
+        assert ops, sub
+        assert all(first_stage("/".join(n)) == "backbone" for n in ops)
+        assert all(n.index("backbone") < n.index(sub) for n in ops), sub
+    assert {first_stage(n) for n in names} - {None} == set(STAGES) - {
+        "distill", "telemetry"}
 
 
 # ---------------------------------------------------------------------------

@@ -113,6 +113,33 @@ def test_crop_patchify_compiles_for_v5e(shape):
     assert out >= FLEET * SHORTLIST_K * gg * cfg.d_model * 4
 
 
+def test_crop_patchify_compiles_for_v5e_at_patch_4(shape):
+    """Swin-T's patch 4 at width 96: eight 16-pixel patches a plane row
+    ([392, 128] planes) against block-diagonal weights [3, 128, 768],
+    whose [392, 768] rows are the [3136, 96] tokens."""
+    from repro.configs import get_config
+    from repro.kernels.crop_patchify.crop_patchify import (
+        crop_patchify_batch,
+    )
+    from repro.kernels.crop_patchify.ops import pack_factor
+
+    cfg = get_config("madeye-approx-swin-t")
+    gg, pp = cfg.token_grid ** 2, cfg.patch ** 2
+    r = pack_factor(gg, pp)
+    assert (gg // r, r * pp, r * cfg.d_model) == (392, 128, 768)
+    c = _compile(
+        lambda *a: crop_patchify_batch(*a, interpret=False),
+        shape((gg // r, r * pp), jnp.int32),
+        shape((gg // r, r * pp), jnp.int32),
+        shape((FLEET, SHORTLIST_K, 4, N_OBJECTS), jnp.int32),
+        shape((FLEET, 3, N_OBJECTS)), shape((FLEET, 3, gg // r, r * pp)),
+        shape((3, r * pp, r * cfg.d_model), jnp.bfloat16),
+        shape((1, r * cfg.d_model)))
+    assert "tpu_custom_call" in c.as_text()
+    out = c.memory_analysis().output_size_in_bytes
+    assert out >= FLEET * SHORTLIST_K * gg * cfg.d_model * 4
+
+
 def test_full_detector_forward_compiles_for_v5e(shape):
     from repro.configs import get_config
     from repro.models.detector import detector_init
@@ -234,3 +261,59 @@ def test_sharded_episode_runs_the_kernel_per_chip_for_v5e(sharded_episode):
                      for op in OP_NAME.findall(line))]
     assert calls
     assert all(tokens in line for line in calls), calls
+
+
+@pytest.fixture(scope="module")
+def swin_episode(shape):
+    """The swin-t-k18-f15 cell's episode (Swin-T at 64 cameras, K = 18
+    of 75 windows), compiled for one chip: its optimized HLO text and
+    its device bytes."""
+    from benchmarks.chip import harness
+    from repro.fleet import prepare_fleet_run
+    from repro.fleet.runner import _episode
+
+    config = harness.load_json("configs", "madeye-approx-swin-t")
+    traffic = harness.load_json("traffic", "k18-f15")
+    prep = prepare_fleet_run(harness.build_spec(
+        config, traffic, 1, harness.draw_seeds(0, FLEET)))
+    args = [jax.tree.map(lambda x: shape(x.shape, x.dtype), tree)
+            for tree in (prep.statics, prep.state, prep.provider)]
+    c = _episode.lower(prep.cfg, prep.wl, *args, metrics=None).compile()
+    return c.as_text(), harness.memory_bytes(c)
+
+
+def test_swin_episode_crops_through_the_packed_kernel_for_v5e(swin_episode):
+    """The Swin cell's crops go through the Pallas kernel under
+    `crop_to_tokens`, on planes of eight patches a row."""
+    hlo, _ = swin_episode
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line
+             and any("/crop_to_tokens/" in op
+                     for op in OP_NAME.findall(line))]
+    assert calls
+    assert all("s32[392,128]" in line and "bf16[3,128,768]" in line
+               for line in calls), calls
+
+
+def test_swin_episode_fits_the_chip_for_v5e(swin_episode):
+    """The backbone runs the [F*K] batch in slices sized from its shapes:
+    the episode needs about a third of the chip (in one shot it would
+    need 14.7 of 16 GiB)."""
+    _, nbytes = swin_episode
+    assert nbytes < 8 * 2**30, nbytes
+
+
+def test_swin_episode_names_its_substages_for_v5e(swin_episode):
+    """Window attention and patch merge ops of the episode sit inside
+    `backbone` (the reducers of a reduce carry a shorter path, from
+    inside the backbone's map over slices)."""
+    from repro.obs.trace import STAGES
+
+    hlo, _ = swin_episode
+    for sub in ("window_attention", "patch_merge"):
+        ops = [op.split("/") for op in OP_NAME.findall(hlo)
+               if sub in op.split("/")]
+        episode = [op for op in ops if op[0] == "jit(_episode)"]
+        assert episode, sub
+        assert all("backbone" in op[:op.index(sub)] for op in episode)
+        assert not any(set(op) & (set(STAGES) - {"backbone"})
+                       for op in ops), sub
